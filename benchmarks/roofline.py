@@ -21,7 +21,9 @@ import json
 import os
 
 from benchmarks.common import emit
-from repro.utils.roofline import HBM_BW, ICI_BW, PEAK_OPS_INT8
+from repro.utils.roofline import PEAKS
+
+V5E = PEAKS["TPU v5 lite"]   # the analytic OMS model below is for this chip
 
 
 def lm_table(out_dir="results/dryrun"):
@@ -55,18 +57,18 @@ def oms_roofline(n_refs=1_160_000, n_queries=2048, dhv=4096, q_block=64,
     vpu_ops = cmp_total * W * 10
     bytes_ = cmp_total * (W * 4) / q_block + n_queries * W * 4
     t_vpu = vpu_ops / 9.6e12
-    t_mem = bytes_ / HBM_BW
+    t_mem = bytes_ / V5E.hbm_bw
     emit("roofline/oms_vpu_paper_faithful", max(t_vpu, t_mem) * 1e6,
          f"tC={t_vpu:.2e}s tM={t_mem:.2e}s "
          f"bneck={'compute' if t_vpu > t_mem else 'memory'}")
-    # MXU (beyond-paper): 2*Dhv int8 ops per comparison at 394 TOPS
+    # MXU (beyond-paper): 2*Dhv int8 ops per comparison at the int8 peak
     mxu_ops = cmp_total * 2 * dhv
-    t_mxu = mxu_ops / PEAK_OPS_INT8
+    t_mxu = mxu_ops / V5E.int8_ops
     emit("roofline/oms_mxu_beyond_paper", max(t_mxu, t_mem) * 1e6,
          f"tC={t_mxu:.2e}s tM={t_mem:.2e}s "
          f"speedup_vs_vpu={max(t_vpu, t_mem)/max(t_mxu, t_mem):.2f}x")
     # collective: winner merge over model axis
-    t_coll = (n_queries * 16) / ICI_BW
+    t_coll = (n_queries * 16) / V5E.ici_bw
     emit("roofline/oms_collective_merge", t_coll * 1e6,
          "16B/query winner merge — negligible by construction")
 
@@ -89,13 +91,18 @@ def tune_sweeps(dim=512, k=2, q_rows=16, r_rows=1024, grid="tiny", iters=3):
         default = next((r for r in rows if r.tiles == want), win)
         speed = (default.median_us / win.median_us
                  if win.median_us > 0 else 0.0)
-        emit(f"tune/{be}/d{dim}_q{q_rows}xr{r_rows}", default.t_bound_us,
+        frac = ("n/a" if win.roofline_frac is None
+                else f"{win.roofline_frac:.5f}")
+        # us_per_call is the modeled bound: 0.0 where the device has no
+        # published peaks (the derived tokens say "n/a")
+        emit(f"tune/{be}/d{dim}_q{q_rows}xr{r_rows}",
+             default.t_bound_us or 0.0,
              f"model_flops={default.model_flops:.0f} "
              f"model_bytes={default.model_bytes:.0f} "
              f"default[{default.tiles_str()}] measured={default.median_us:.1f}us "
              f"winner[{win.tiles_str()}] measured={win.median_us:.1f}us "
              f"speedup_vs_default={speed:.2f}x "
-             f"roofline_frac={win.roofline_frac:.5f}")
+             f"roofline_frac={frac}")
 
 
 def main():

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-PAD_PMZ = jnp.float32(jnp.finfo(jnp.float32).max)
+PAD_PMZ = np.float32(np.finfo(np.float32).max)
 
 
 @jax.tree_util.register_pytree_node_class

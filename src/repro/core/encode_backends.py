@@ -18,8 +18,9 @@ Built-in backends:
   ----------  ------  -----------------------------------------------------
   oracle      encode  pure-jnp reference; materialises (batch, P, D) bits
   word_tiled  encode  jnp, Dhv looped in word tiles; (batch, P, WT*32) bits
-  pallas      encode  Pallas hdencode kernel (VMEM word tiles; interpret-
-                      mode fallback off-TPU); (spectra_tile, P, WT*32) bits
+  pallas      encode  Pallas hdencode kernel (VMEM word tiles; interpret
+                      mode only, refused where kernels compile);
+                      (spectra_tile, P, WT*32) bits
   fused       fused   preprocess + word-tiled encode in ONE jit per chunk
 
 Every backend is required — and tested (tests/test_encode_backends.py) — to

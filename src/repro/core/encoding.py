@@ -147,7 +147,7 @@ def preprocess_spectra(
 #   * ``word_tiled`` — :func:`encode_spectra_word_tiled`, bounded unpacked
 #     intermediate (the default production path);
 #   * ``pallas`` — the repro.kernels.hdencode Pallas kernel, dispatched from
-#     :func:`encode_spectra_batched` (interpret-mode on CPU, compiled on TPU);
+#     :func:`encode_spectra_batched` (interpret mode on the CPU only);
 #   * ``fused`` — one jitted preprocess->encode chunk loop.
 # All backends are required (and tested) to be bit-identical to the oracle,
 # ties, masked rows and padding included.

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.blocking import ReferenceDB, shard_reference_db
 from repro.core.search import SearchParams, _search_sorted_padded
@@ -40,9 +39,7 @@ def _merge_best(sim, row, axis_name, k: int):
     S, Q = sims.shape[0], sims.shape[1]
     sims = jnp.moveaxis(sims, 0, 1).reshape(Q, S * k)
     rows = jnp.moveaxis(rows, 0, 1).reshape(Q, S * k)
-    best, arg = _select_topk(sims, k)            # (Q, k) sims + columns
-    r = jnp.take_along_axis(rows, jnp.clip(arg, 0, S * k - 1), axis=1)
-    return best, jnp.where(arg >= 0, r, -1)
+    return _select_topk(sims, k, payload=rows)   # (Q, k) sims + rows
 
 
 def sharded_search(db: ReferenceDB, q_hvs, q_pmz, q_charge,
@@ -82,11 +79,11 @@ def sharded_search(db: ReferenceDB, q_hvs, q_pmz, q_charge,
         open_b, open_row = _merge_best(open_b, open_row, model_axis, params.top_k)
         return std_b, std_row, open_b, open_row
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(db_specs, P(), P(), P()),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(db, q_hvs, q_pmz, q_charge), db
 

@@ -1,3 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's hot spots: the §II-C Hamming search (VPU
+and MXU formulations) and the §II-A HD encoder."""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_default() -> bool:
+    """Pallas interpret mode is the default on the CPU backend only.
+
+    Any other platform compiles the kernels for real, so a platform the
+    kernels cannot lower to fails loudly instead of silently falling back
+    to the interpreter.
+    """
+    return jax.default_backend() == "cpu"

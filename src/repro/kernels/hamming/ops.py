@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.hamming import hamming as _k
 
 PAD_PMZ = float(jnp.finfo(jnp.float32).max)
@@ -14,12 +15,11 @@ PAD_PMZ = float(jnp.finfo(jnp.float32).max)
 # kernel call, so the padded copies (and the kernel's output tile) — not the
 # raw (Q, Rk) extents — are what bounds device memory; the peak_intermediate
 # contracts in repro.core.backends read these to stay honest about that.
+# A 128-word chunk fills the TPU's 128 vector lanes; narrower chunks pad
+# every vreg and multiply the VMEM footprint of the popcount intermediate.
 Q_TILE = 16
 R_TILE = 256
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+WORD_TILE = 128
 
 
 def _pad_rows(x, mult, value=0):
@@ -32,9 +32,9 @@ def _pad_rows(x, mult, value=0):
 
 @partial(jax.jit, static_argnames=("q_tile", "r_tile", "word_tile", "interpret"))
 def hamming_matrix(q, r, *, q_tile: int = Q_TILE, r_tile: int = R_TILE,
-                   word_tile: int = 16, interpret: bool | None = None):
+                   word_tile: int = WORD_TILE, interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     Q, R = q.shape[0], r.shape[0]
     W = q.shape[1]
     wt = min(word_tile, W)
@@ -53,11 +53,11 @@ def hamming_matrix(q, r, *, q_tile: int = Q_TILE, r_tile: int = R_TILE,
                                    "interpret"))
 def fused_search(q_hvs, r_hvs, q_pmz, r_pmz, q_charge, r_charge, *, dim: int,
                  k: int = 1, ppm_tol: float = 20.0, open_tol_da: float = 75.0,
-                 q_tile: int = Q_TILE, r_tile: int = R_TILE, word_tile: int = 16,
-                 interpret: bool | None = None):
+                 q_tile: int = Q_TILE, r_tile: int = R_TILE,
+                 word_tile: int = WORD_TILE, interpret: bool | None = None):
     """Fused dual-window top-k search; returns four (Q, k) int32 arrays."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     Q = q_hvs.shape[0]
     W = q_hvs.shape[1]
     wt = min(word_tile, W)

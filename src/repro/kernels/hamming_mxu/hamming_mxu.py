@@ -15,9 +15,11 @@ to ±1 int8 inside VMEM right before feeding the MXU:
     HBM traffic:   packed (Dhv/8 bytes per HV)   — paper-faithful compression
     compute:       int8 MXU matmul               — TPU-native throughput
 
-Layout note: the unpacked (tile, 32*wt) int8 operands are built with the bit
-index minor and word-chunk-major, i.e. bit b of word w lands at column
-w*32 + b — identical for q and r, so the contraction is consistent.
+Layout note: the unpack is by bit plane — plane b holds bit b of every word
+of a wt-word chunk as a (tile, wt) ±1 int8 matrix, and the dot is the sum of
+the 32 plane matmuls, each contracting over the chunk's words. The word axis
+stays on the vector lanes, so no in-kernel reshape is needed, and integer
+addition makes the plane order irrelevant to the result.
 """
 from __future__ import annotations
 
@@ -27,43 +29,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.topk import merge_topk, select_topk
+from repro.kernels.hamming import hamming as _vpu
 
 
-def _unpack_pm1(words: jax.Array) -> jax.Array:
-    """(N, wt) uint32 -> (N, wt*32) int8 in {+1, -1} (bit0 -> +1)."""
-    n, wt = words.shape
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = (words[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
-    pm1 = (1 - 2 * bits.astype(jnp.int32)).astype(jnp.int8)
-    return pm1.reshape(n, wt * 32)
+def _pm1_plane(words: jax.Array, b: int) -> jax.Array:
+    """(N, wt) uint32 -> (N, wt) int8: bit b of each word as ±1 (0 -> +1)."""
+    bit = (words >> jnp.uint32(b)) & jnp.uint32(1)
+    return (1 - 2 * bit.astype(jnp.int32)).astype(jnp.int8)
 
 
-def _dot_tile(q, r, wt: int):
-    """(QT, W) x (RT, W) packed words -> (QT, RT) int32 ±1 dot product."""
-    QT, W = q.shape
-    RT = r.shape[0]
-    n_chunks = W // wt
-
-    def body(c, acc):
-        qc = jax.lax.dynamic_slice(q, (0, c * wt), (QT, wt))
-        rc = jax.lax.dynamic_slice(r, (0, c * wt), (RT, wt))
-        qb = _unpack_pm1(qc)   # (QT, wt*32) int8 — VMEM-resident
-        rb = _unpack_pm1(rc)   # (RT, wt*32) int8
-        return acc + jax.lax.dot_general(
-            qb, rb, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)
-
-    return jax.lax.fori_loop(0, n_chunks, body, jnp.zeros((QT, RT), jnp.int32))
+def _dot_tile(q_ref, r_ref, wt: int):
+    """(QT, W) x (RT, W) packed-word refs -> (QT, RT) int32 ±1 dot product,
+    in static wt-word chunks (the caller guarantees W % wt == 0)."""
+    acc = 0
+    for s in range(0, q_ref.shape[1], wt):
+        q = q_ref[:, s:s + wt]
+        r = r_ref[:, s:s + wt]
+        for b in range(32):
+            acc = acc + jax.lax.dot_general(
+                _pm1_plane(q, b), _pm1_plane(r, b),
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.int32)
+    return acc
 
 
 def hamming_mxu_kernel(q_ref, r_ref, out_ref, *, dim: int, wt: int):
-    dot = _dot_tile(q_ref[...], r_ref[...], wt)
-    out_ref[...] = (dim - dot) // 2
+    out_ref[...] = (dim - _dot_tile(q_ref, r_ref, wt)) // 2
 
 
 def hamming_matrix_mxu_pallas(q, r, *, dim: int, q_tile: int = 128,
-                              r_tile: int = 256, word_tile: int = 16,
+                              r_tile: int = 256, word_tile: int = 128,
                               interpret: bool = True):
     """All-pairs Hamming (Q, R) int32 via the MXU formulation.
 
@@ -89,59 +84,23 @@ def hamming_matrix_mxu_pallas(q, r, *, dim: int, q_tile: int = 128,
 # Fused dual-window search on the MXU (§II-C kernel, MXU formulation)
 # ---------------------------------------------------------------------------
 #
-# Same structure as repro.kernels.hamming.fused_search_kernel — grid over
-# (q-tile, r-tile), sequential last axis, running top-k winners accumulated
-# under pl.when(j == 0) init — but the Hamming tile comes from the ±1 int8
-# MXU matmul instead of xor+popcount. The dot is exact integer arithmetic,
-# so the winners are bit-identical to the VPU kernel's.
+# The VPU kernel's fused body (repro.kernels.hamming.fused_search_kernel —
+# grid over (q-tile, r-tile), sequential last axis, running top-k winners)
+# with the Hamming tile from the ±1 int8 MXU matmul instead of xor+popcount.
+# The dot is exact integer arithmetic, so the winners are bit-identical to
+# the VPU kernel's.
 
 
-def fused_search_mxu_kernel(q_ref, r_ref, qp_ref, rp_ref, qc_ref, rc_ref,
-                            std_sim_ref, std_idx_ref, open_sim_ref,
-                            open_idx_ref, *, dim: int, wt: int, r_tile: int,
-                            k: int, ppm_tol: float, open_tol_da: float,
-                            pad_pmz: float):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        std_sim_ref[...] = jnp.full_like(std_sim_ref[...], -1)
-        std_idx_ref[...] = jnp.full_like(std_idx_ref[...], -1)
-        open_sim_ref[...] = jnp.full_like(open_sim_ref[...], -1)
-        open_idx_ref[...] = jnp.full_like(open_idx_ref[...], -1)
-
-    dot = _dot_tile(q_ref[...], r_ref[...], wt)
-    sims = dim - (dim - dot) // 2                       # = dim - hamming
-
-    qp = qp_ref[...]
-    rp = rp_ref[...]
-    qc = qc_ref[...]
-    rc = rc_ref[...]
-
-    dpmz = jnp.abs(qp[:, None] - rp[None, :])
-    valid = (rp[None, :] < pad_pmz) & (qc[:, None] == rc[None, :])
-    std_mask = valid & (dpmz <= qp[:, None] * (ppm_tol * 1e-6))
-    open_mask = valid & (dpmz <= open_tol_da)
-
-    base = (j * r_tile).astype(jnp.int32)
-
-    def update(mask, sim_out, idx_out):
-        ts, tc = select_topk(jnp.where(mask, sims, jnp.int32(-1)), k)
-        ti = jnp.where(tc >= 0, base + tc, jnp.int32(-1))
-        # running winners first: earlier blocks (lower idx) win sim ties
-        ms, mi = merge_topk(sim_out[...], idx_out[...], ts, ti, k)
-        sim_out[...] = ms
-        idx_out[...] = mi
-
-    update(std_mask, std_sim_ref, std_idx_ref)
-    update(open_mask, open_sim_ref, open_idx_ref)
+def mxu_sims(q_ref, r_ref, *, dim: int, wt: int):
+    """(QT, RT) int32 similarity ``dim - hamming`` from the ±1 dot."""
+    return dim - (dim - _dot_tile(q_ref, r_ref, wt)) // 2
 
 
 def fused_search_mxu_pallas(q_hvs, r_hvs, q_pmz, r_pmz, q_charge, r_charge,
                             *, dim: int, k: int = 1, ppm_tol: float = 20.0,
                             open_tol_da: float = 75.0,
                             q_tile: int = 32, r_tile: int = 256,
-                            word_tile: int = 16, pad_pmz: float | None = None,
+                            word_tile: int = 128, pad_pmz: float | None = None,
                             interpret: bool = True):
     """Returns (std_sim, std_idx, open_sim, open_idx), each (Q, k) int32.
 
@@ -149,30 +108,9 @@ def fused_search_mxu_pallas(q_hvs, r_hvs, q_pmz, r_pmz, q_charge, r_charge,
     ``r_hvs`` or -1; rank order (sim desc, row asc); ``k`` static), with
     the Hamming tile computed on the MXU. Requires dim == 32 * W.
     """
-    Q, W = q_hvs.shape
-    R = r_hvs.shape[0]
-    if pad_pmz is None:
-        pad_pmz = float(jnp.finfo(jnp.float32).max)
-    grid = (Q // q_tile, R // r_tile)
-
-    kern = functools.partial(
-        fused_search_mxu_kernel, dim=dim, wt=word_tile, r_tile=r_tile, k=k,
-        ppm_tol=ppm_tol, open_tol_da=open_tol_da, pad_pmz=pad_pmz)
-
-    out2d = pl.BlockSpec((q_tile, k), lambda i, j: (i, 0))
-    shapes = [jax.ShapeDtypeStruct((Q, k), jnp.int32)] * 4
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((q_tile, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((r_tile, W), lambda i, j: (j, 0)),
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((r_tile,), lambda i, j: (j,)),
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((r_tile,), lambda i, j: (j,)),
-        ],
-        out_specs=[out2d, out2d, out2d, out2d],
-        out_shape=shapes,
-        interpret=interpret,
-    )(q_hvs, r_hvs, q_pmz, r_pmz, q_charge, r_charge)
+    return _vpu.fused_search_pallas(
+        q_hvs, r_hvs, q_pmz, r_pmz, q_charge, r_charge, dim=dim, k=k,
+        ppm_tol=ppm_tol, open_tol_da=open_tol_da, q_tile=q_tile,
+        r_tile=r_tile, pad_pmz=pad_pmz,
+        sims_fn=functools.partial(mxu_sims, dim=dim, wt=word_tile),
+        interpret=interpret)

@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.hamming_mxu import hamming_mxu as _k
 
 PAD_PMZ = float(jnp.finfo(jnp.float32).max)
@@ -16,11 +17,7 @@ PAD_PMZ = float(jnp.finfo(jnp.float32).max)
 # (routed through repro.tune.tiles_for, which may substitute tuned tiles).
 Q_TILE = 32
 R_TILE = 256
-WORD_TILE = 16
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+WORD_TILE = 128   # one 128-lane chunk per matmul (see hamming.ops)
 
 
 def effective_tiles(Q: int, R: int, W: int, *, q_tile: int = Q_TILE,
@@ -57,7 +54,7 @@ def hamming_matrix(q, r, dim: int, *, q_tile: int = Q_TILE,
                    r_tile: int = R_TILE,
                    word_tile: int = WORD_TILE, interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     Q, W = q.shape
     R = r.shape[0]
     if dim != W * 32:
@@ -85,7 +82,7 @@ def fused_search(q_hvs, r_hvs, q_pmz, r_pmz, q_charge, r_charge, *, dim: int,
     PAD_PMZ (masked out in-kernel), and the outputs slice back to Q rows.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     Q, W = q_hvs.shape
     R = r_hvs.shape[0]
     if dim != W * 32:

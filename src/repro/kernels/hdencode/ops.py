@@ -1,4 +1,12 @@
-"""Jitted wrapper for the hdencode kernel."""
+"""Jitted wrapper for the hdencode kernel.
+
+The kernel runs in Pallas interpret mode only. Compiled for a TPU it is
+refused: its (n_bins, word_tile) codebook block breaks the rule that a
+block's last two dims be multiples of (8, 128), and a block widened to
+128 words would put an 18 MiB codebook slice plus an in-kernel
+``jnp.take`` gather into VMEM. Selecting it where the kernels compile
+therefore raises instead of failing deep inside the compiler.
+"""
 from __future__ import annotations
 
 from functools import partial
@@ -6,11 +14,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.hdencode import hdencode as _k
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("spectra_tile", "word_tile", "interpret"))
@@ -18,7 +23,12 @@ def hdencode(bins, levels, mask, id_hvs, level_hvs, tiebreak, *,
              spectra_tile: int = 16, word_tile: int = 8,
              interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
+    if not interpret:
+        raise NotImplementedError(
+            "the 'pallas' encode backend has no compiled TPU lowering (its "
+            "codebook block and in-kernel gather do not fit the TPU "
+            "compiler); use encode_backend 'word_tiled' or 'fused'")
     B = bins.shape[0]
     W = id_hvs.shape[1]
     st = min(spectra_tile, B) if B else spectra_tile

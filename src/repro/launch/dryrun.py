@@ -136,6 +136,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     roof = rl.Roofline(flops=flops, hbm_bytes=hbm_bytes,
                        coll_bytes=coll_total, chips=1,
+                       peaks=rl.PEAKS["TPU v5 lite"],   # the pods modeled
                        model_flops=model_flops / chips)
 
     arg_bytes_per_dev = _sharded_arg_bytes(cell.args, cell.in_specs, mesh)

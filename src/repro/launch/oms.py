@@ -57,6 +57,16 @@ identification PER QUERY (each query competes only against its own top-k
 narrow matches), so coalescing never changes an answer. Corpus-level FDR
 statistics remain the ``search`` subcommand's job.
 
+One process per accelerator: a chip belongs to the process that first
+touches it. ``queries`` therefore pins itself to the CPU, so in a
+``queries | serve`` pipe ``serve`` holds the chip. In the hot-reload flow
+``serve`` holds it too, and the second process that grows the store runs
+on the CPU: ``JAX_PLATFORMS=cpu ... build --append``.
+
+Every subcommand but ``queries`` keeps JAX's persistent compilation cache
+in ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else in ``.jax_cache/``
+at the root of the checkout (``enable_compile_cache``).
+
 ``serve`` production knobs: ``--deadline-ms`` sheds requests the queue
 cannot meet (fast-fail with an error response), ``--tenant`` names the
 traffic source for round-robin fair batching, ``--result-cache``/
@@ -69,6 +79,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import threading
 import time
@@ -82,6 +93,25 @@ import numpy as np
 from repro.core import OMSConfig, OMSPipeline, backends, encode_backends
 from repro.core.blocking import candidate_block_stats
 from repro.data.spectra import LibraryConfig, make_dataset
+
+# JAX's persistent compilation cache for this checkout: one fixed path, so
+# every later run of the checkout finds the programs compiled before it.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache JAX uses and
+    nothing is changed here; otherwise the cache is ``COMPILE_CACHE_DIR``.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def _dataset_args(ap, refs_default=8192):
@@ -102,8 +132,9 @@ def _encode_backend_args(ap):
     ap.add_argument("--encode-backend", default="word_tiled",
                     choices=encode_backends.names(),
                     help="'word_tiled' bounds the unpacked intermediate; "
-                         "'pallas' is the VMEM-tiled kernel; 'fused' runs "
-                         "preprocess+encode in one jit")
+                         "'pallas' is the VMEM-tiled kernel (CPU interpret "
+                         "mode only); 'fused' runs preprocess+encode in one "
+                         "jit")
     ap.add_argument("--encode-batch", type=int, default=512,
                     help="spectra per encode chunk (memory bound)")
 
@@ -307,31 +338,43 @@ def cmd_search(argv) -> None:
     _tune_stats_line("oms search")
 
 
+def request_lines(queries):
+    """One ``serve`` request JSON line per spectrum of a SpectraSet, ids
+    counting from 0; zero-intensity padding peaks are dropped (encoding is
+    peak-set based)."""
+    mz = np.asarray(queries.mz)
+    inten = np.asarray(queries.intensity)
+    pmz = np.asarray(queries.pmz)
+    charge = np.asarray(queries.charge)
+    for i in range(mz.shape[0]):
+        keep = inten[i] > 0
+        yield json.dumps(
+            {"id": i, "pmz": float(pmz[i]), "charge": int(charge[i]),
+             "mz": [float(v) for v in mz[i][keep]],
+             "intensity": [float(v) for v in inten[i][keep]]},
+            sort_keys=True, separators=(",", ":")) + "\n"
+
+
 def cmd_queries(argv) -> None:
-    """Emit a synthetic query workload as JSON-lines (pipes into `serve`)."""
+    """Emit a synthetic query workload as JSON-lines (pipes into `serve`).
+
+    Runs on the CPU (``main`` pins it there): in a ``queries | serve`` pipe
+    the accelerator belongs to the ``serve`` process."""
     ap = argparse.ArgumentParser(prog="repro.launch.oms queries")
     ap.add_argument("--queries", type=int, default=512)
     ap.add_argument("--open-tol", type=float, default=75.0)
     _dataset_args(ap)
     args = ap.parse_args(argv)
-
-    qs = _dataset(args).queries
-    mz = np.asarray(qs.mz)
-    inten = np.asarray(qs.intensity)
-    pmz = np.asarray(qs.pmz)
-    charge = np.asarray(qs.charge)
-    for i in range(mz.shape[0]):
-        keep = inten[i] > 0          # drop padding; encode is peak-set based
-        sys.stdout.write(json.dumps(
-            {"id": i, "pmz": float(pmz[i]), "charge": int(charge[i]),
-             "mz": [float(v) for v in mz[i][keep]],
-             "intensity": [float(v) for v in inten[i][keep]]},
-            sort_keys=True, separators=(",", ":")) + "\n")
+    sys.stdout.writelines(request_lines(_dataset(args).queries))
 
 
 def cmd_serve(argv) -> None:
-    """Online JSON-lines serve loop: micro-batched, streamed by default."""
-    from repro.serve import MicroBatcher, QuerySpec
+    """Online JSON-lines serve loop: micro-batched, streamed by default.
+
+    Every request is answered, with an error object where it could not be
+    served. Exits 1 when a micro-batch failed for any reason other than a
+    shed deadline or a malformed request line."""
+    from repro.serve import DeadlineExceeded, MicroBatcher, QuerySpec
 
     ap = argparse.ArgumentParser(prog="repro.launch.oms serve")
     ap.add_argument("--store", required=True, help="store directory")
@@ -530,13 +573,15 @@ def cmd_serve(argv) -> None:
                 cache.put(keys[i], fresh[j])
         return payloads
 
-    def emit(rid, fut):
+    def emit(rid, fut, malformed):
         # One bad request (or a poisoned micro-batch) answers with an error
         # object; the serve loop itself must stay up for everyone else.
         try:
             payload = fut.result()
         except Exception as e:
             payload = {"error": f"{type(e).__name__}: {e}"}
+            if not (malformed or isinstance(e, DeadlineExceeded)):
+                state["failed"] += 1
         sys.stdout.write(json.dumps({"id": rid, **payload}, sort_keys=True,
                                     separators=(",", ":")) + "\n")
         sys.stdout.flush()
@@ -551,7 +596,7 @@ def cmd_serve(argv) -> None:
     n = 0
     n_bad = 0
     t0 = time.perf_counter()
-    state = {"answered": 0}
+    state = {"answered": 0, "failed": 0}
     hb_stop = threading.Event()
 
     def heartbeat():
@@ -578,6 +623,7 @@ def cmd_serve(argv) -> None:
             if not line:
                 continue
             rid = None
+            malformed = False
             try:
                 req = json.loads(line)
                 rid = req.get("id")
@@ -586,6 +632,10 @@ def cmd_serve(argv) -> None:
                                                       np.float32),
                                  pmz=float(req["pmz"]),
                                  charge=int(req["charge"]))
+                if spec.mz.ndim != 1 or spec.mz.shape != spec.intensity.shape:
+                    # caught here, not in the batch it would poison
+                    raise ValueError("mz and intensity must be lists of "
+                                     "equal length")
                 ddl_ms = float(req.get("deadline_ms", args.deadline_ms))
                 fut = batcher.submit(
                     spec,
@@ -593,9 +643,10 @@ def cmd_serve(argv) -> None:
                     tenant=str(req.get("tenant", args.tenant)))
             except Exception as e:      # malformed line: answer, don't die
                 n_bad += 1
+                malformed = True
                 fut = Future()
                 fut.set_exception(e)
-            pending.append((rid, fut))
+            pending.append((rid, fut, malformed))
             n += 1
             while pending and pending[0][1].done():  # stream out, in order
                 emit(*pending.popleft())
@@ -625,6 +676,8 @@ def cmd_serve(argv) -> None:
         if reloads.value:
             stats += f", {reloads.value} hot-reloads"
         bad = f", {n_bad} malformed rejected" if n_bad else ""
+        if state["failed"]:
+            bad += f", {state['failed']} failed in their micro-batch"
         print(f"[oms serve] answered {n} queries in {dt:.2f}s "
               f"({n / max(dt, 1e-9):.0f} q/s, {batcher.n_batches} "
               f"micro-batches{stats}{bad})", file=sys.stderr)
@@ -647,6 +700,8 @@ def cmd_serve(argv) -> None:
         print(f"[oms serve] trace: {n_ev} spans -> {args.trace}{dropped}",
               file=sys.stderr)
     _tune_stats_line("oms serve")
+    if state["failed"]:
+        raise SystemExit(1)
 
 
 def cmd_trace_report(argv) -> None:
@@ -829,8 +884,13 @@ def cmd_oneshot(argv) -> None:
 
 
 def main(argv=None):
-    import sys
     argv = list(sys.argv[1:] if argv is None else argv)
+    if argv and argv[0] == "queries":
+        # Before anything touches a device: the generator must not hold the
+        # accelerator that the `serve` end of the pipe needs.
+        jax.config.update("jax_platforms", "cpu")
+    else:
+        enable_compile_cache()
     if argv and argv[0] == "build":
         cmd_build(argv[1:])
     elif argv and argv[0] == "search":

@@ -59,7 +59,7 @@ def kernel_defaults(backend: str) -> dict[str, int]:
     if backend in ("kernel_vpu", "fused"):
         from repro.kernels.hamming import ops as hops
         return {"q_tile": hops.Q_TILE, "r_tile": hops.R_TILE,
-                "word_tile": 16}
+                "word_tile": hops.WORD_TILE}
     if backend == "rescore":
         return {"row_bucket": DEFAULT_ROW_BUCKET_LO}
     raise ValueError(f"backend {backend!r} is not tunable; "

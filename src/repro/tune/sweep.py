@@ -45,7 +45,7 @@ FUSED_BACKENDS = ("fused", "fused_mxu")
 GRIDS: dict[str, dict[str, dict[str, tuple[int, ...]]]] = {
     "default": {
         "kernel": {"q_tile": (16, 32, 64), "r_tile": (128, 256, 512),
-                   "word_tile": (8, 16)},
+                   "word_tile": (64, 128)},
         "rescore": {"row_bucket": (32, 64, 128, 256)},
     },
     "tiny": {
@@ -63,8 +63,10 @@ class SweepRow:
     median_us: float
     model_flops: float = 0.0      # hlo_cost-modeled FLOPs (trip-weighted)
     model_bytes: float = 0.0      # hlo_cost-modeled HBM bytes
-    t_bound_us: float = 0.0       # roofline bound from the modeled terms
-    roofline_frac: float = 0.0    # t_bound / measured (measured-vs-roofline)
+    # roofline bound from the modeled terms and t_bound / measured; None on
+    # a device without published peaks (repro.utils.roofline.PEAKS)
+    t_bound_us: float | None = None
+    roofline_frac: float | None = None
 
     def tiles_str(self) -> str:
         return " ".join(f"{n}={v}" for n, v in sorted(self.tiles.items()))
@@ -222,8 +224,9 @@ def sweep_backend(backend: str, *, dim: int, k: int, q_rows: int,
     ``timer(fn, args, tiles) -> seconds`` overrides wall timing (tests);
     ``model=False`` skips the compile+hlo_cost pass.
     """
-    from repro.utils.roofline import Roofline
+    from repro.utils.roofline import Roofline, peaks_for
 
+    peaks = peaks_for(device_kind())
     case = make_case(backend, dim=dim, k=k, q_rows=q_rows, r_rows=r_rows,
                      seed=seed)
     uflops = useful_flops(dim, q_rows, r_rows)
@@ -233,14 +236,15 @@ def sweep_backend(backend: str, *, dim: int, k: int, q_rows: int,
         t = (timer(fn, args, tiles) if timer is not None
              else _median_time(fn, args, iters))
         flops, nbytes = _modeled_cost(fn, args) if model else (0.0, 0.0)
-        roof = Roofline(flops=flops, hbm_bytes=nbytes, coll_bytes=0.0,
-                        chips=1, model_flops=uflops)
-        t_bound = roof.t_bound
+        t_bound = Roofline(flops=flops, hbm_bytes=nbytes, coll_bytes=0.0,
+                           chips=1, peaks=peaks, model_flops=uflops).t_bound
+        modeled = t_bound is not None
         rows.append(SweepRow(
             backend=backend, tiles=dict(tiles), median_us=t * 1e6,
             model_flops=flops, model_bytes=nbytes,
-            t_bound_us=t_bound * 1e6,
-            roofline_frac=(t_bound / t) if t > 0 else 0.0))
+            t_bound_us=t_bound * 1e6 if modeled else None,
+            roofline_frac=(t_bound / t if t > 0 else 0.0) if modeled
+            else None))
     rows.sort(key=SweepRow.sort_key)
     return rows
 
@@ -282,7 +286,8 @@ def save_winners(path, results: dict[str, list[SweepRow]], *, dim: int,
         w = rows[0]
         cache.put(device_kind=device_kind(), backend=be,
                   tiles=w.tiles, median_us=round(w.median_us, 1),
-                  roofline_frac=round(w.roofline_frac, 6),
+                  roofline_frac=(None if w.roofline_frac is None
+                                 else round(w.roofline_frac, 6)),
                   git_rev=git_rev,
                   **cache_key_for(be, dim=dim, k=k, q_rows=q_rows,
                                   r_rows=r_rows))
@@ -299,7 +304,11 @@ def format_table(results: dict[str, list[SweepRow]], *,
         rows = results[be][:1] if winners_only else results[be]
         for i, r in enumerate(rows):
             star = "*" if i == 0 else " "
-            lines.append(
-                f"{be:<12} {r.tiles_str():<38} {r.median_us:>10.1f} "
-                f"{r.t_bound_us:>10.2f} {r.roofline_frac * 100:>8.3f}%{star}")
+            if r.t_bound_us is None:
+                bound, frac = f"{'n/a':>10}", f"{'n/a':>9}"
+            else:
+                bound = f"{r.t_bound_us:>10.2f}"
+                frac = f"{r.roofline_frac * 100:>8.3f}%"
+            lines.append(f"{be:<12} {r.tiles_str():<38} "
+                         f"{r.median_us:>10.1f} {bound} {frac}{star}")
     return "\n".join(lines)

@@ -81,8 +81,7 @@ class TestDtypeStabilityTruePositive:
         # Under default x64-disabled jax the promotion is silently
         # truncated, so the toy must run with x64 enabled to produce the
         # real 64-bit equation the contract exists to catch.
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             jaxpr = jax.make_jaxpr(
                 lambda x: x.astype(jnp.int64) + 1)(np.zeros(4, np.int32))
         res = C.check_dtype_stability(jaxpr, target="test:toy")

@@ -88,7 +88,6 @@ def test_pipeline_parallel_forward():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.pipeline import pipeline_forward
 
         n_stages, n_micro, mb, d = 4, 8, 2, 16
@@ -105,9 +104,9 @@ def test_pipeline_parallel_forward():
             return pipeline_forward(layer_fn, ws_local[0], x,
                                     n_stages=n_stages, n_micro=n_micro)
 
-        fn = shard_map(staged, mesh=mesh,
-                       in_specs=(P("pipe"), P()), out_specs=P("pipe"),
-                       check_rep=False)
+        fn = jax.shard_map(staged, mesh=mesh,
+                           in_specs=(P("pipe"), P()), out_specs=P("pipe"),
+                           check_vma=False)
         with mesh:
             stacked = fn(ws, x)          # (n_stages*n_micro, mb, d)
         got = stacked[:n_micro]          # stage 0 holds the final outputs

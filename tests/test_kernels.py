@@ -101,7 +101,7 @@ def test_mxu_effective_tiles_clamp():
     qt, rt, wt = mops.effective_tiles(5, 70, 7)
     assert qt == 5 and rt == 70
     assert wt == 7 and 7 % wt == 0
-    qt, rt, wt = mops.effective_tiles(64, 1024, 16)
+    qt, rt, wt = mops.effective_tiles(64, 1024, 2 * mops.WORD_TILE)
     assert (qt, rt, wt) == (mops.Q_TILE, mops.R_TILE, mops.WORD_TILE)
     # word_tile that doesn't divide W steps down to the largest divisor
     assert mops.effective_tiles(8, 8, 6, word_tile=4)[2] == 3

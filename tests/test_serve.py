@@ -901,3 +901,72 @@ def test_stats_threadsafe_under_concurrent_search_and_reload(setup):
     assert eng.total_stats.scanned_rows == K * M * per_rows
     assert eng.total_stats.slabs_scanned == K * M * per_slabs
     assert eng.last_stats.scanned_rows == per_rows
+
+
+# ---------------------------------------------------------------------------
+# Launcher exit status: a failed micro-batch is not a clean run
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("case", ["ok", "malformed", "batch_fails"])
+def test_serve_exit_status_flags_failed_batches(setup, monkeypatch, capsys,
+                                               case):
+    """``oms.py serve`` answers every request line, with an error object
+    where it must. It exits 1 only when a micro-batch failed for a reason
+    other than a shed deadline or a malformed line — here a search that
+    raises — and a malformed line (mz/intensity lengths differ) is refused
+    on its own instead of poisoning its micro-batch."""
+    import io
+    import json
+
+    from repro.launch import oms
+
+    ds, _, store, _ = setup
+    served = type(ds.queries)(*(np.asarray(a)[:4] for a in ds.queries))
+    lines = list(oms.request_lines(served))
+    if case == "malformed":
+        lines.insert(2, json.dumps({"id": "bad", "pmz": 500.0, "charge": 2,
+                                    "mz": [300.0, 400.0],
+                                    "intensity": [1.0]}) + "\n")
+    if case == "batch_fails":
+        def boom(*a, **k):
+            raise RuntimeError("scan failed")
+        monkeypatch.setattr(OMSPipeline, "search_encoded", boom)
+    monkeypatch.setattr("sys.stdin", io.StringIO("".join(lines)))
+    argv = ["--store", store.path, "--max-r", "32", "--q-block", "8",
+            "--no-result-cache"]
+    if case == "batch_fails":
+        with pytest.raises(SystemExit) as exit_info:
+            oms.cmd_serve(argv)
+        assert exit_info.value.code == 1
+    else:
+        oms.cmd_serve(argv)
+    out = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    assert len(out) == len(lines)
+    errors = [r["id"] for r in out if "error" in r]
+    want = {"ok": [], "malformed": ["bad"], "batch_fails": [0, 1, 2, 3]}
+    assert errors == want[case]
+
+
+def test_launcher_import_initialises_no_backend():
+    """``oms.py queries`` pins JAX to the CPU inside ``main`` (a ``queries |
+    serve`` pipe must leave the accelerator to ``serve``). That pin only
+    takes effect if importing the launcher initialised no backend, so a
+    platform set after the import must still be the one JAX tries."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = ("import jax, repro.launch.oms\n"
+            "jax.config.update('jax_platforms', 'no_such_platform')\n"
+            "try:\n"
+            "    jax.devices()\n"
+            "except RuntimeError as e:\n"
+            "    print('PIN_HONOURED' if 'no_such_platform' in str(e) "
+            "else e)\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       env=dict(os.environ, PYTHONPATH=src))
+    assert r.returncode == 0, r.stderr
+    assert "PIN_HONOURED" in r.stdout, r.stdout + r.stderr
