@@ -273,8 +273,10 @@ def _obs_results(fx: _Fixture) -> list[C.ContractResult]:
     tracer must (a) leave the traced hot jaxpr byte-identical — host-side
     spans cannot inject host-transfer prims into a program they never
     enter — and (b) change zero result bytes of a real resident AND
-    streamed search. The tracer must also actually record spans during
-    the instrumented calls, or the check would be vacuous."""
+    streamed search. The traced side runs under a profiler session, so the
+    spans' profiler annotations are exercised too. The tracer must also
+    actually record every span of a search during the instrumented calls,
+    or the check would be vacuous."""
     from repro.obs import trace as trace_mod
 
     target = "serve:obs"
@@ -293,8 +295,9 @@ def _obs_results(fx: _Fixture) -> list[C.ContractResult]:
     res_off = snapshot()
     tracer = trace_mod.install(trace_mod.Tracer())
     try:
-        jaxpr_on = str(_trace_search(fx, fx.resident.db, base))
-        res_on = snapshot()
+        with tempfile.TemporaryDirectory() as d, jax.profiler.trace(d):
+            jaxpr_on = str(_trace_search(fx, fx.resident.db, base))
+            res_on = snapshot()
     finally:
         trace_mod.uninstall()
 
@@ -318,8 +321,10 @@ def _obs_results(fx: _Fixture) -> list[C.ContractResult]:
             "resident+streamed results byte-identical with tracer "
             "installed"))
     names = {ev.name for ev in tracer.events()}
-    expected = {"pipeline.plan", "pipeline.scan", "pipeline.fdr",
-                "serve.scan"}
+    expected = {"pipeline.search", "pipeline.precursors_to_host",
+                "pipeline.plan", "pipeline.scan", "search.sort_pad",
+                "search.gather", "search.kernel", "search.restore",
+                "pipeline.fdr", "serve.scan"}
     missing = expected - names
     if missing:
         results.append(C.ContractResult(

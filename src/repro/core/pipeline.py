@@ -362,55 +362,59 @@ class OMSPipeline:
                        prefix_margin: int | None = None) -> OMSOutput:
         """Search already-encoded query HVs (callers that hold the encoded
         batch — the serving launcher, rescoring loops — avoid re-encoding)."""
-        # One host conversion, shared by plan_search and the padding plan —
-        # oms_search itself never syncs device->host.
-        qp_np = np.asarray(q_pmz)
-        qc_np = np.asarray(q_charge)
-        with span("pipeline.plan", queries=int(qp_np.shape[0])):
-            params = self.search_params(qp_np, qc_np, exhaustive=exhaustive,
-                                        open_tol_da=open_tol_da,
-                                        backend=backend, top_k=top_k,
-                                        prefix_words=prefix_words,
-                                        prefix_margin=prefix_margin)
-        scan_span = span("pipeline.scan", backend=params.backend,
-                         path="streamed" if self.engine is not None
-                         else "resident")
-        if self.engine is not None:
-            with scan_span:
-                result = self.engine.search_encoded(
-                    hvs, q_pmz, q_charge, params, dim=self.cfg.dim,
-                    q_pmz_np=qp_np, q_charge_np=qc_np)
-            # Decoy flags come from the host layout sidecar — the streamed
-            # serve path never uploads library-sized arrays to the device.
-            isd_np = self.engine.layout.is_decoy
-            n_rows = self.engine.layout.n_rows
+        with span("pipeline.search"):
+            # One host conversion, shared by plan_search and the padding
+            # plan — oms_search itself never syncs device->host.
+            with span("pipeline.precursors_to_host"):
+                qp_np = np.asarray(q_pmz)
+                qc_np = np.asarray(q_charge)
+            with span("pipeline.plan", queries=int(qp_np.shape[0])):
+                params = self.search_params(
+                    qp_np, qc_np, exhaustive=exhaustive,
+                    open_tol_da=open_tol_da, backend=backend, top_k=top_k,
+                    prefix_words=prefix_words, prefix_margin=prefix_margin)
+            scan_span = span("pipeline.scan", backend=params.backend,
+                             path="streamed" if self.engine is not None
+                             else "resident")
+            if self.engine is not None:
+                with scan_span:
+                    result = self.engine.search_encoded(
+                        hvs, q_pmz, q_charge, params, dim=self.cfg.dim,
+                        q_pmz_np=qp_np, q_charge_np=qc_np)
+                # Decoy flags come from the host layout sidecar — the
+                # streamed serve path never uploads library-sized arrays to
+                # the device.
+                isd_np = self.engine.layout.is_decoy
+                n_rows = self.engine.layout.n_rows
 
-            def _fdr(row, sim):
-                valid, isd = row_match_flags(row, isd_np, n_rows)
-                return fdr_filter(jnp.asarray(sim).astype(jnp.float32),
-                                  jnp.asarray(isd), jnp.asarray(valid),
-                                  threshold=self.cfg.fdr_threshold)
-        else:
-            row_meta = {}
-            if params.prefix_words:
-                row_pmz, row_charge, _ = self._host_sidecars
-                row_meta = dict(row_pmz_np=row_pmz, row_charge_np=row_charge)
-            with scan_span:
-                result = oms_search(self.db, hvs, q_pmz, q_charge, params,
-                                    dim=self.cfg.dim, q_pmz_np=qp_np,
-                                    q_charge_np=qc_np, **row_meta)
+                def _fdr(row, sim):
+                    valid, isd = row_match_flags(row, isd_np, n_rows)
+                    return fdr_filter(jnp.asarray(sim).astype(jnp.float32),
+                                      jnp.asarray(isd), jnp.asarray(valid),
+                                      threshold=self.cfg.fdr_threshold)
+            else:
+                row_meta = {}
+                if params.prefix_words:
+                    row_pmz, row_charge, _ = self._host_sidecars
+                    row_meta = dict(row_pmz_np=row_pmz,
+                                    row_charge_np=row_charge)
+                with scan_span:
+                    result = oms_search(self.db, hvs, q_pmz, q_charge, params,
+                                        dim=self.cfg.dim, q_pmz_np=qp_np,
+                                        q_charge_np=qc_np, **row_meta)
 
-            def _fdr(row, sim):
-                valid = row >= 0
-                isd = (self.db.is_decoy[jnp.clip(row, 0, self.db.n_rows - 1)]
-                       & valid)
-                return fdr_filter(sim.astype(jnp.float32), isd, valid,
-                                  threshold=self.cfg.fdr_threshold)
+                def _fdr(row, sim):
+                    valid = row >= 0
+                    isd = (self.db.is_decoy[
+                        jnp.clip(row, 0, self.db.n_rows - 1)] & valid)
+                    return fdr_filter(sim.astype(jnp.float32), isd, valid,
+                                      threshold=self.cfg.fdr_threshold)
 
-        with span("pipeline.fdr"):
-            open_fdr = _fdr(result.open_row, result.open_sim)
-            std_fdr = _fdr(result.std_row, result.std_sim)
-        return OMSOutput(result=result, open_fdr=open_fdr, std_fdr=std_fdr)
+            with span("pipeline.fdr"):
+                open_fdr = _fdr(result.open_row, result.open_sim)
+                std_fdr = _fdr(result.std_row, result.std_sim)
+            return OMSOutput(result=result, open_fdr=open_fdr,
+                             std_fdr=std_fdr)
 
     # ------------------------------------------------------------------
     # Cascaded narrow→open identification (see repro.core.cascade)

@@ -52,6 +52,7 @@ import numpy as np
 from repro.core import backends as backends_mod
 from repro.core.blocking import PAD_PMZ, ReferenceDB
 from repro.kernels.topk import select_topk as _select_topk
+from repro.obs.trace import span
 
 # Charge multiplier for building monotonic (charge, pmz) sort keys. PMZ values
 # are clipped below this, so keys from different charges never interleave.
@@ -505,15 +506,17 @@ def sort_pad_plan(q_pmz: jax.Array, q_charge: jax.Array, q_block: int, *,
     Shared by the resident ``oms_search`` and the streaming serve engine so
     both consume literally the same query layout.
     """
-    Q = q_pmz.shape[0]
-    key = jnp.clip(q_pmz, 0.0, _CHARGE_KEY - 1.0) + q_charge * _CHARGE_KEY
-    order = jnp.argsort(key)
-    qc_np = np.asarray(q_charge if q_charge_np is None else q_charge_np)
-    counts = np.unique(qc_np, return_counts=True)[1]
-    sel_np, real_np = _padding_plan(q_block, tuple(int(c) for c in counts))
-    gather = order[jnp.asarray(sel_np)]
-    keep = jnp.flatnonzero(jnp.asarray(real_np), size=Q)
-    unpad = keep[jnp.argsort(order)]
+    with span("search.sort_pad"):
+        Q = q_pmz.shape[0]
+        key = jnp.clip(q_pmz, 0.0, _CHARGE_KEY - 1.0) + q_charge * _CHARGE_KEY
+        order = jnp.argsort(key)
+        qc_np = np.asarray(q_charge if q_charge_np is None else q_charge_np)
+        counts = np.unique(qc_np, return_counts=True)[1]
+        sel_np, real_np = _padding_plan(q_block,
+                                        tuple(int(c) for c in counts))
+        gather = order[jnp.asarray(sel_np)]
+        keep = jnp.flatnonzero(jnp.asarray(real_np), size=Q)
+        unpad = keep[jnp.argsort(order)]
     return gather, unpad
 
 
@@ -564,35 +567,34 @@ def oms_search(db: ReferenceDB, q_hvs: jax.Array, q_pmz: jax.Array,
         validate_prefix_words(params, dim)
     gather, unpad = sort_pad_plan(q_pmz, q_charge, params.q_block,
                                   q_charge_np=q_charge_np)
-    qh = q_hvs[gather]
-    qp = q_pmz[gather]
-    qc = q_charge[gather]
+    with span("search.gather"):
+        qh = q_hvs[gather]
+        qp = q_pmz[gather]
+        qc = q_charge[gather]
     # Padding queries keep their charge (so the block is charge-pure) but are
     # discarded on output.
 
-    if params.prefix_words:
-        if row_pmz_np is None:
-            row_pmz_np = np.asarray(db.pmz)
-        if row_charge_np is None:
-            row_charge_np = np.asarray(db.charge)
-        if q_pmz_np is None:
-            q_pmz_np = np.asarray(q_pmz)
-        if q_charge_np is None:
-            q_charge_np = np.asarray(q_charge)
-        std_b, std_row, open_b, open_row = _prefix_search_padded(
-            db, qh, qp, qc, params=params, dim=dim,
-            row_pmz_np=row_pmz_np, row_charge_np=row_charge_np,
-            qp_np=q_pmz_np, qc_np=q_charge_np)
-    else:
-        std_b, std_row, open_b, open_row = _search_sorted_padded(
-            db, qh, qp, qc, params=params, dim=dim)
+    with span("search.kernel"):
+        if params.prefix_words:
+            if row_pmz_np is None:
+                row_pmz_np = np.asarray(db.pmz)
+            if row_charge_np is None:
+                row_charge_np = np.asarray(db.charge)
+            if q_pmz_np is None:
+                q_pmz_np = np.asarray(q_pmz)
+            if q_charge_np is None:
+                q_charge_np = np.asarray(q_charge)
+            std_b, std_row, open_b, open_row = _prefix_search_padded(
+                db, qh, qp, qc, params=params, dim=dim,
+                row_pmz_np=row_pmz_np, row_charge_np=row_charge_np,
+                qp_np=q_pmz_np, qc_np=q_charge_np)
+        else:
+            std_b, std_row, open_b, open_row = _search_sorted_padded(
+                db, qh, qp, qc, params=params, dim=dim)
 
     # Drop padding rows, restore original query order.
     def _restore(x):
         return x[unpad]
-
-    std_b, std_row = _restore(std_b), _restore(std_row)
-    open_b, open_row = _restore(open_b), _restore(open_row)
 
     def _finalize(best, row):
         ok = (best >= params.min_sim) & (row >= 0)
@@ -600,8 +602,11 @@ def oms_search(db: ReferenceDB, q_hvs: jax.Array, q_pmz: jax.Array,
         ok = ok & (idx >= 0)  # padding rows carry orig_idx == -1
         return jnp.where(ok, idx, -1), jnp.where(ok, best, -1), jnp.where(ok, row, -1)
 
-    std_idx, std_sim, std_row = _finalize(std_b, std_row)
-    open_idx, open_sim, open_row = _finalize(open_b, open_row)
+    with span("search.restore"):
+        std_b, std_row = _restore(std_b), _restore(std_row)
+        open_b, open_row = _restore(open_b), _restore(open_row)
+        std_idx, std_sim, std_row = _finalize(std_b, std_row)
+        open_idx, open_sim, open_row = _finalize(open_b, open_row)
     return SearchResult(std_idx, std_sim, open_idx, open_sim, std_row, open_row)
 
 

@@ -5,27 +5,21 @@
 ``--trace out.trace.jsonl`` (JSON-lines) is loaded back into
 :class:`~repro.obs.trace.TraceEvent` rows, validated against the export
 schema (CI's obs-smoke job fails on any malformed event), and rolled up
-per span name: count, total wall time, share of the traced wall clock,
-deterministic p50/p95/p99 from a fixed-bucket histogram over the span
-durations, plus summed ``rows``/``bytes`` attributes where the
-instrumentation recorded them. That table IS the paper's per-stage
-encode/scan/merge split, reproduced from a real serve session.
+per span name: count, total wall time, self time (the total less the time
+of the span's direct children, linked by ``parent_id``), share of the
+traced wall clock, exact nearest-rank p50/p95/p99 of the span durations,
+plus summed ``rows``/``bytes`` attributes where the instrumentation
+recorded them. That table IS the paper's per-stage encode/scan/merge
+split, reproduced from a real serve session. Files written without span
+ids still load; their spans have no children, so self time equals total.
 """
 from __future__ import annotations
 
 import json
+import math
 from typing import Iterable
 
-from repro.obs.metrics import Histogram
-from repro.obs.trace import TraceEvent
-
-# Span-duration buckets for the rollup percentiles (us): ~exponential
-# 10us .. 60s, finer than the serve-side latency buckets because traces
-# also carry sub-ms host stages (plan, merge).
-_DUR_BUCKETS_US = (
-    10.0, 25.0, 50.0, 100.0, 250.0, 500.0, 1e3, 2.5e3, 5e3, 1e4, 2.5e4,
-    5e4, 1e5, 2.5e5, 5e5, 1e6, 2.5e6, 5e6, 1e7, 3e7, 6e7,
-)
+from repro.obs.trace import ID_KEYS, TraceEvent
 
 # Numeric attrs summed into the rollup when present on an event.
 SUMMED_ATTRS = ("rows", "bytes")
@@ -40,6 +34,16 @@ def _require(cond: bool, msg: str) -> None:
         raise TraceFormatError(msg)
 
 
+def _ids(obj: dict, where: str) -> dict:
+    """The span ids of an exported event (absent in files written without
+    them)."""
+    ids = {k: obj[k] for k in ID_KEYS if k in obj}
+    for k, v in ids.items():
+        _require(isinstance(v, int) and not isinstance(v, bool) and v >= 0,
+                 f"{where}: {k} must be a non-negative integer")
+    return ids
+
+
 def _event_from_jsonl(obj: dict, lineno: int) -> TraceEvent:
     _require(isinstance(obj, dict), f"line {lineno}: not a JSON object")
     for key in ("name", "ts_us", "dur_us", "tid"):
@@ -51,10 +55,11 @@ def _event_from_jsonl(obj: dict, lineno: int) -> TraceEvent:
     _require(isinstance(obj["dur_us"], (int, float)) and obj["dur_us"] >= 0,
              f"line {lineno}: dur_us must be a non-negative number")
     attrs = {k: v for k, v in obj.items()
-             if k not in ("name", "ts_us", "dur_us", "tid")}
+             if k not in ("name", "ts_us", "dur_us", "tid", *ID_KEYS)}
     t0 = int(obj["ts_us"] * 1e3)
     return TraceEvent(obj["name"], t0, t0 + int(obj["dur_us"] * 1e3),
-                      int(obj["tid"]), attrs)
+                      int(obj["tid"]), attrs,
+                      **_ids(obj, f"line {lineno}"))
 
 
 def _event_from_chrome(obj: dict, i: int) -> TraceEvent:
@@ -73,7 +78,9 @@ def _event_from_chrome(obj: dict, i: int) -> TraceEvent:
                                      f"object")
     t0 = int(obj["ts"] * 1e3)
     return TraceEvent(obj["name"], t0, t0 + int(obj["dur"] * 1e3),
-                      int(obj["tid"]), args)
+                      int(obj["tid"]),
+                      {k: v for k, v in args.items() if k not in ID_KEYS},
+                      **_ids(args, f"traceEvents[{i}]"))
 
 
 def load_trace(path: str) -> list[TraceEvent]:
@@ -115,31 +122,44 @@ def load_trace(path: str) -> list[TraceEvent]:
 # ---------------------------------------------------------------------------
 
 
+def _nearest_rank(sorted_values: list[float], q: float) -> float:
+    """The smallest value with at least a share ``q`` of the values at or
+    below it: an observed duration, not an interpolation."""
+    return sorted_values[max(0, math.ceil(q * len(sorted_values)) - 1)]
+
+
 def rollup(events: Iterable[TraceEvent]) -> dict[str, dict]:
-    """Per span name: {count, total_us, p50_us, p95_us, p99_us, rows,
-    bytes}. Percentiles come from a fixed-bucket histogram over span
-    durations — deterministic for identical traces."""
+    """Per span name: {count, total_us, self_us, p50_us, p95_us, p99_us,
+    rows, bytes}. Self time is the total less the durations of the spans
+    whose ``parent_id`` is one of this name's spans."""
+    events = list(events)
+    child_ns: dict[int, int] = {}
+    for ev in events:
+        if ev.parent_id:
+            child_ns[ev.parent_id] = child_ns.get(ev.parent_id, 0) + ev.dur_ns
     out: dict[str, dict] = {}
+    durs: dict[str, list[float]] = {}
     for ev in events:
         agg = out.get(ev.name)
         if agg is None:
             agg = out[ev.name] = {
-                "count": 0, "total_us": 0.0,
-                "_hist": Histogram(_DUR_BUCKETS_US),
+                "count": 0, "total_us": 0.0, "self_us": 0.0,
                 **{k: 0 for k in SUMMED_ATTRS},
             }
+            durs[ev.name] = []
         agg["count"] += 1
         agg["total_us"] += ev.dur_ns / 1e3
-        agg["_hist"].observe(ev.dur_ns / 1e3)
+        agg["self_us"] += (ev.dur_ns - child_ns.get(ev.span_id, 0)) / 1e3
+        durs[ev.name].append(ev.dur_ns / 1e3)
         for k in SUMMED_ATTRS:
             v = ev.attrs.get(k)
             if isinstance(v, (int, float)) and not isinstance(v, bool):
                 agg[k] += v
-    for agg in out.values():
-        h = agg.pop("_hist")
-        agg["p50_us"] = h.p50
-        agg["p95_us"] = h.p95
-        agg["p99_us"] = h.p99
+    for name, agg in out.items():
+        d = sorted(durs[name])
+        agg["p50_us"] = _nearest_rank(d, 0.50)
+        agg["p95_us"] = _nearest_rank(d, 0.95)
+        agg["p99_us"] = _nearest_rank(d, 0.99)
     return out
 
 
@@ -154,13 +174,15 @@ def _fmt_us(us: float) -> str:
 def format_table(roll: dict[str, dict]) -> str:
     """The trace-report table, widest stage first."""
     total_us = sum(a["total_us"] for a in roll.values()) or 1.0
-    header = (f"{'span':<28} {'count':>7} {'total':>10} {'share':>6} "
-              f"{'p50':>9} {'p95':>9} {'p99':>9} {'rows':>12} {'bytes':>14}")
+    header = (f"{'span':<28} {'count':>7} {'total':>10} {'self':>10} "
+              f"{'share':>6} {'p50':>9} {'p95':>9} {'p99':>9} {'rows':>12} "
+              f"{'bytes':>14}")
     lines = [header, "-" * len(header)]
     for name in sorted(roll, key=lambda n: -roll[n]["total_us"]):
         a = roll[name]
         lines.append(
             f"{name:<28} {a['count']:>7} {_fmt_us(a['total_us']):>10} "
+            f"{_fmt_us(a['self_us']):>10} "
             f"{100 * a['total_us'] / total_us:>5.1f}% "
             f"{_fmt_us(a['p50_us']):>9} {_fmt_us(a['p95_us']):>9} "
             f"{_fmt_us(a['p99_us']):>9} "

@@ -15,22 +15,35 @@ Installing a :class:`Tracer` turns the same call sites into real spans
 that record ``(name, t_start_ns, t_end_ns, attrs)`` into a bounded ring
 buffer (old events are evicted, never the serve loop blocked).
 
+Every live span carries three ids: its own ``span_id``, the ``parent_id``
+of the innermost span open on the same thread when it began (0 for a
+root), and the ``trace_id`` of its root span. The same span also opens a
+``jax.profiler.TraceAnnotation`` of its name with those ids as arguments,
+so under a profiler session each span lands in the profiler's host trace,
+on the device trace's clock, where a reader picks program spans out by
+their ``span_id`` stat. With no profiler session the annotation records
+nothing.
+
 Export formats:
 
   * ``to_jsonl``  — one JSON object per line: ``{"name", "ts_us",
-    "dur_us", "tid", ...attrs}`` (grep/jq-friendly);
+    "dur_us", "tid", "span_id", "parent_id", "trace_id", ...attrs}``
+    (grep/jq-friendly);
   * ``to_chrome`` — Chrome ``trace_event`` JSON (``{"traceEvents":
-    [...]}``, complete ``"ph": "X"`` events) that https://ui.perfetto.dev
-    and ``chrome://tracing`` open directly.
+    [...]}``, complete ``"ph": "X"`` events, the ids in ``args``) that
+    https://ui.perfetto.dev and ``chrome://tracing`` open directly.
 
-This module is DEPENDENCY-FREE (stdlib only) on purpose: it is imported
-at module level from ``repro.core.pipeline``, ``repro.serve.engine`` and
-``repro.serve.scheduler`` — both sides of the core<->serve boundary — so
-importing anything from ``repro`` here would create a cycle. The
-``analyze --imports`` leaf-module check enforces this.
+This module imports only the stdlib at module level on purpose: it is
+imported from ``repro.core.pipeline``, ``repro.core.search``,
+``repro.serve.engine`` and ``repro.serve.scheduler`` — both sides of the
+core<->serve boundary — so importing anything from ``repro`` here would
+create a cycle. The ``analyze --imports`` leaf-module check enforces this.
+The profiler's annotation class is looked up when a :class:`Tracer` is
+made, so the disabled path never touches JAX.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -48,10 +61,27 @@ class TraceEvent(NamedTuple):
     t_end_ns: int
     tid: int                      # recording thread ident
     attrs: Mapping[str, Any]      # small JSON-able payload (rows, bytes, ...)
+    span_id: int = 0              # 0: recorded without ids
+    parent_id: int = 0            # innermost open span on the thread, or 0
+    trace_id: int = 0             # the root span's id
 
     @property
     def dur_ns(self) -> int:
         return self.t_end_ns - self.t_start_ns
+
+
+ID_KEYS = ("span_id", "parent_id", "trace_id")
+
+
+def _ids(ev: TraceEvent) -> dict:
+    return {k: getattr(ev, k) for k in ID_KEYS}
+
+
+class _OpenSpans(threading.local):
+    """Per thread: the stack of live spans, innermost last."""
+
+    def __init__(self):
+        self.stack: list = []
 
 
 class Tracer:
@@ -65,16 +95,23 @@ class Tracer:
     def __init__(self, capacity: int = 1 << 16):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
+        from jax.profiler import TraceAnnotation
+
         self.capacity = capacity
         self._buf: deque[TraceEvent] = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._recorded = 0
+        self._annotation = TraceAnnotation
+        self._ids = itertools.count(1)     # next() is atomic under the GIL
+        self._open = _OpenSpans()
 
     # ------------------------------------------------------------------
     def record(self, name: str, t_start_ns: int, t_end_ns: int,
-               attrs: Mapping[str, Any] | None = None) -> None:
+               attrs: Mapping[str, Any] | None = None, span_id: int = 0,
+               parent_id: int = 0, trace_id: int = 0) -> None:
         ev = TraceEvent(name, int(t_start_ns), int(t_end_ns),
-                        threading.get_ident(), attrs or {})
+                        threading.get_ident(), attrs or {}, span_id,
+                        parent_id, trace_id)
         with self._lock:
             self._buf.append(ev)
             self._recorded += 1
@@ -120,7 +157,7 @@ class Tracer:
             "traceEvents": [
                 {"name": ev.name, "ph": "X", "pid": pid, "tid": ev.tid,
                  "ts": ev.t_start_ns / 1e3, "dur": ev.dur_ns / 1e3,
-                 "args": dict(ev.attrs)}
+                 "args": {**ev.attrs, **_ids(ev)}}
                 for ev in events
             ],
         }
@@ -136,6 +173,7 @@ def event_dict(ev: TraceEvent) -> dict:
     d = {"name": ev.name, "ts_us": ev.t_start_ns / 1e3,
          "dur_us": ev.dur_ns / 1e3, "tid": ev.tid}
     d.update(ev.attrs)
+    d.update(_ids(ev))
     return d
 
 
@@ -145,10 +183,12 @@ def event_dict(ev: TraceEvent) -> dict:
 
 
 class _Span:
-    """A live span: clock read on enter, record on exit. ``add(**attrs)``
-    attaches facts learned mid-span (bytes fetched, rows survived)."""
+    """A live span: ids, profiler annotation and clock read on enter,
+    record on exit. ``add(**attrs)`` attaches facts learned mid-span (bytes
+    fetched, rows survived)."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_ann", "_stack",
+                 "span_id", "parent_id", "trace_id")
 
     def __init__(self, tracer: Tracer, name: str, attrs: dict):
         self._tracer = tracer
@@ -159,12 +199,27 @@ class _Span:
         self._attrs.update(attrs)
 
     def __enter__(self) -> "_Span":
+        t = self._tracer
+        self._stack = stack = t._open.stack
+        self.span_id = next(t._ids)
+        if stack:
+            self.parent_id, self.trace_id = stack[-1].span_id, stack[-1].trace_id
+        else:
+            self.parent_id, self.trace_id = 0, self.span_id
+        stack.append(self)
+        self._ann = t._annotation(self._name, span_id=self.span_id,
+                                  parent_id=self.parent_id,
+                                  trace_id=self.trace_id)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer.record(self._name, self._t0, time.perf_counter_ns(),
-                            self._attrs)
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        self._stack.remove(self)
+        self._tracer.record(self._name, self._t0, t1, self._attrs,
+                            self.span_id, self.parent_id, self.trace_id)
 
 
 class _NoopSpan:

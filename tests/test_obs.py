@@ -55,6 +55,38 @@ def test_span_records_name_attrs_and_midspan_add():
     assert t.n_recorded == 1                   # uninstall really detaches
 
 
+def test_span_ids_link_children_to_their_parent_on_the_same_thread():
+    t = install(Tracer())
+    with span("root") as root:
+        with span("a") as a:
+            with span("leaf") as leaf:
+                pass
+        with span("b") as b:
+            other = []
+            th = threading.Thread(target=lambda: other.append(
+                span("elsewhere").__enter__()))
+            th.start()
+            th.join(timeout=10)
+            assert not th.is_alive()
+            other[0].__exit__(None, None, None)
+    with span("next") as nxt:
+        pass
+    assert root.parent_id == 0 and root.trace_id == root.span_id
+    assert (a.parent_id, b.parent_id) == (root.span_id, root.span_id)
+    assert leaf.parent_id == a.span_id
+    assert {a.trace_id, b.trace_id, leaf.trace_id} == {root.span_id}
+    # another thread's span has no parent here; a later root starts a trace
+    assert other[0].parent_id == 0 and other[0].trace_id == other[0].span_id
+    assert nxt.parent_id == 0 and nxt.trace_id == nxt.span_id
+    ids = [root.span_id, a.span_id, leaf.span_id, b.span_id,
+           other[0].span_id, nxt.span_id]
+    assert len(set(ids)) == len(ids) and 0 not in ids
+    got = {ev.name: (ev.span_id, ev.parent_id, ev.trace_id)
+           for ev in t.events()}
+    assert got["leaf"] == (leaf.span_id, a.span_id, root.span_id)
+    assert got["root"] == (root.span_id, 0, root.span_id)
+
+
 def test_tracer_ring_buffer_bounds_memory():
     t = Tracer(capacity=4)
     for i in range(10):
@@ -84,6 +116,22 @@ def _sample_tracer() -> Tracer:
     return t
 
 
+def _nested_tracer() -> Tracer:
+    """search(1) > plan(2), scan(3) > kernel(4); times in ns."""
+    t = Tracer()
+    t.record("search", 0, 10_000_000, {}, 1, 0, 1)
+    t.record("plan", 1_000_000, 3_000_000, {}, 2, 1, 1)
+    t.record("kernel", 4_000_000, 8_000_000, {"rows": 4}, 4, 3, 1)
+    t.record("scan", 3_000_000, 9_000_000, {}, 3, 1, 1)
+    return t
+
+
+def _export(t: Tracer, tmp_path, fmt: str) -> str:
+    path = str(tmp_path / ("t.jsonl" if fmt == "jsonl" else "t.json"))
+    t.to_jsonl(path) if fmt == "jsonl" else t.to_chrome(path)
+    return path
+
+
 @pytest.mark.parametrize("fmt", ["jsonl", "chrome"])
 def test_export_round_trips_through_loader(tmp_path, fmt):
     t = _sample_tracer()
@@ -94,6 +142,40 @@ def test_export_round_trips_through_loader(tmp_path, fmt):
     assert [ev.name for ev in events] == ["encode", "scan", "scan"]
     assert events[0].dur_ns == 2_000_000
     assert events[1].attrs["rows"] == 11
+    nested = report_mod.load_trace(_export(_nested_tracer(), tmp_path, fmt))
+    assert nested == _nested_tracer().events()   # ids out of attrs, intact
+
+
+@pytest.mark.parametrize("fmt", ["memory", "jsonl", "chrome"])
+def test_rollup_self_time_subtracts_direct_children(tmp_path, fmt):
+    t = _nested_tracer()
+    events = (t.events() if fmt == "memory"
+              else report_mod.load_trace(_export(t, tmp_path, fmt)))
+    roll = report_mod.rollup(events)
+    assert {n: a["self_us"] for n, a in roll.items()} == pytest.approx(
+        {"search": 2000.0, "plan": 2000.0, "scan": 2000.0, "kernel": 4000.0})
+    assert roll["search"]["total_us"] == pytest.approx(10000.0)
+    assert roll["kernel"]["rows"] == 4
+    header = report_mod.format_table(roll).splitlines()[0].split()
+    assert header[:5] == ["span", "count", "total", "self", "share"]
+
+
+@pytest.mark.parametrize("fmt", ["jsonl", "chrome"])
+def test_loader_reads_files_written_without_ids(tmp_path, fmt):
+    ev = {"name": "scan", "ts_us": 1.0, "dur_us": 5.0, "tid": 3, "rows": 2}
+    path = str(tmp_path / ("old.jsonl" if fmt == "jsonl" else "old.json"))
+    with open(path, "w") as f:
+        if fmt == "jsonl":
+            f.write(json.dumps(ev) + "\n")
+        else:
+            json.dump({"traceEvents": [
+                {"name": "scan", "ph": "X", "ts": 1.0, "dur": 5.0, "pid": 1,
+                 "tid": 3, "args": {"rows": 2}}]}, f)
+    (got,) = report_mod.load_trace(path)
+    assert (got.span_id, got.parent_id, got.trace_id) == (0, 0, 0)
+    assert got.attrs == {"rows": 2}
+    roll = report_mod.rollup([got, got])
+    assert roll["scan"]["self_us"] == roll["scan"]["total_us"] == 10.0
 
 
 def test_chrome_export_is_valid_trace_event_json(tmp_path):
@@ -112,6 +194,10 @@ def test_chrome_export_is_valid_trace_event_json(tmp_path):
     ('{"name": "x", "ts_us": 1, "tid": 3}', "missing 'dur_us'"),
     ('{"name": "x", "ts_us": 1, "dur_us": -2, "tid": 3}', "non-negative"),
     ('{"name": "", "ts_us": 1, "dur_us": 2, "tid": 3}', "non-empty"),
+    ('{"name": "x", "ts_us": 1, "dur_us": 2, "tid": 3, "span_id": -1}',
+     "span_id must be a non-negative integer"),
+    ('{"name": "x", "ts_us": 1, "dur_us": 2, "tid": 3, "parent_id": 1.5}',
+     "parent_id must be a non-negative integer"),
     ("not json", "invalid JSON"),
 ])
 def test_loader_rejects_malformed_jsonl(tmp_path, line, msg):
@@ -146,8 +232,10 @@ def test_rollup_counts_totals_and_summed_attrs(tmp_path):
     assert roll["scan"]["total_us"] == pytest.approx(7000.0)
     assert roll["scan"]["rows"] == 12 and roll["scan"]["bytes"] == 48
     assert roll["encode"]["rows"] == 5 and roll["encode"]["bytes"] == 0
-    # percentiles are static bucket bounds — deterministic
-    assert roll["scan"]["p50_us"] in report_mod._DUR_BUCKETS_US
+    # exact nearest-rank percentiles of the durations (6000, 1000 us)
+    assert roll["scan"]["p50_us"] == 1000.0
+    assert roll["scan"]["p95_us"] == roll["scan"]["p99_us"] == 6000.0
+    assert roll["encode"]["p50_us"] == roll["encode"]["p99_us"] == 2000.0
     table = report_mod.format_table(roll)
     assert table.splitlines()[2].startswith("scan")    # widest stage first
     assert "encode" in table
@@ -248,3 +336,57 @@ def test_traced_search_byte_identical_and_spans_recorded():
         assert a.tobytes() == b.tobytes(), f
     names = {ev.name for ev in t.events()}
     assert {"pipeline.plan", "pipeline.scan", "pipeline.fdr"} <= names
+
+
+SEARCH_SPANS = {   # name -> parent's name, as search_encoded nests them
+    "pipeline.search": None,
+    "pipeline.precursors_to_host": "pipeline.search",
+    "pipeline.plan": "pipeline.search", "pipeline.scan": "pipeline.search",
+    "pipeline.fdr": "pipeline.search", "search.sort_pad": "pipeline.scan",
+    "search.gather": "pipeline.scan", "search.kernel": "pipeline.scan",
+    "search.restore": "pipeline.scan"}
+
+
+def test_profiled_search_writes_every_span_with_its_ids(tmp_path):
+    """Under a profiler session each span also lands in the profiler's host
+    trace, with the ids the ring buffer holds, nested as the code runs."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core import OMSConfig, OMSPipeline
+    from repro.data.spectra import LibraryConfig, make_dataset
+
+    cfg = OMSConfig(dim=256, n_levels=8, max_r=32, q_block=8)
+    ds = make_dataset(LibraryConfig(n_refs=200, n_queries=16, seed=7))
+    pipe = OMSPipeline(cfg, ds.refs)
+    hvs, qp, qc = pipe.encode_queries(ds.queries)
+    pipe.search_encoded(hvs, qp, qc)            # compile outside the trace
+    t = install(Tracer())
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            jax.block_until_ready(pipe.search_encoded(hvs, qp, qc))
+    finally:
+        uninstall()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    profiled = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                st = dict(e.stats)
+                if "span_id" in st:
+                    profiled[st["span_id"]] = (
+                        e.name, st["parent_id"], st["trace_id"],
+                        e.start_ns, e.start_ns + e.duration_ns)
+    ring = {ev.span_id: (ev.name, ev.parent_id, ev.trace_id)
+            for ev in t.events()}
+    assert {v[:3] for v in profiled.values()} == set(ring.values())
+    assert sorted(v[0] for v in profiled.values()) == sorted(SEARCH_SPANS)
+    for sid, (name, parent, trace, t0, t1) in profiled.items():
+        if SEARCH_SPANS[name] is None:
+            assert parent == 0 and trace == sid
+            continue
+        p_name, _, p_trace, p0, p1 = profiled[parent]
+        assert p_name == SEARCH_SPANS[name] and trace == p_trace
+        assert p0 <= t0 and t1 <= p1
