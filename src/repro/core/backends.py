@@ -18,7 +18,10 @@ Built-in backends:
 
   name        kind    engine
   ----------  ------  -----------------------------------------------------
-  vpu         matrix  packed XOR + lax.population_count (XLA, paper-faithful)
+  vpu         matrix  packed XOR + lax.population_count (paper-faithful):
+                      on TPU the blocked scan step runs the in-place Pallas
+                      kernel (its ``scan``), XLA on CPU and in the
+                      prefix/rescore tiles
   mxu         matrix  ±1 int8 matmul  (D - x·yᵀ)/2  (XLA, MXU formulation)
   kernel_vpu  matrix  Pallas all-pairs Hamming tile kernel
   kernel_mxu  matrix  Pallas MXU Hamming kernel
@@ -61,19 +64,34 @@ class Backend:
     kind: str          # MATRIX | FUSED
     fn: Callable
     # Matrix backend whose tile fn serves this FUSED backend's prefix/
-    # rescore stages (see hamming_tile_fn); None falls back to "vpu".
+    # rescore stages (see tile_backend); None falls back to "vpu".
     tile_name: str | None = None
+    # True where a Pallas kernel computes fn's result.
+    pallas: bool = False
+    # Optional in-place blocked-scan step of a MATRIX backend: ``scan(q_hvs,
+    # hvs, start_row, rk)`` is fn's tile against rows ``[start_row,
+    # start_row + rk)`` of the whole library ``hvs``, read where they lie
+    # (a Pallas kernel); the scan takes it where ``scan_fits(max_r,
+    # n_words)`` holds for the library's blocking.
+    scan: Callable | None = None
+    scan_fits: Callable[[int, int], bool] | None = None
+
+    def scans_in_place(self, max_r: int, n_words: int) -> bool:
+        return self.scan is not None and self.scan_fits(max_r, n_words)
 
 
 _REGISTRY: dict[str, Backend] = {}
 
 
 def register(name: str, kind: str, fn: Callable, *,
-             tile_name: str | None = None) -> Backend:
+             tile_name: str | None = None, pallas: bool = False,
+             scan: Callable | None = None,
+             scan_fits: Callable[[int, int], bool] | None = None) -> Backend:
     if kind not in (MATRIX, FUSED):
         raise ValueError(f"backend kind must be {MATRIX!r} or {FUSED!r}, "
                          f"got {kind!r}")
-    be = Backend(name=name, kind=kind, fn=fn, tile_name=tile_name)
+    be = Backend(name=name, kind=kind, fn=fn, tile_name=tile_name,
+                 pallas=pallas, scan=scan, scan_fits=scan_fits)
     _REGISTRY[name] = be
     return be
 
@@ -143,17 +161,32 @@ def _fused_xla(q, r, qp, rp, qc, rc, *, dim, ppm_tol, open_tol_da, k):
                              ppm_tol=ppm_tol, open_tol_da=open_tol_da)
 
 
-register("vpu", MATRIX, lambda q, r, dim: packing.hamming_matrix_packed(q, r))
+def _vpu_scan(q, hvs, start_row, rk):
+    from repro.kernels.hamming import ops as hops
+    return hops.scan_tile(q, hvs, start_row, rk=rk)
+
+
+def _vpu_scan_fits(max_r: int, n_words: int) -> bool:
+    """On TPU, where the kernel takes the library's blocking; the CPU (where
+    Pallas would only interpret) keeps the XLA tile."""
+    from repro.kernels import interpret_default
+    from repro.kernels.hamming import ops as hops
+    return not interpret_default() and hops.scan_tile_fits(max_r, n_words)
+
+
+register("vpu", MATRIX, lambda q, r, dim: packing.hamming_matrix_packed(q, r),
+         scan=_vpu_scan, scan_fits=_vpu_scan_fits)
 register("mxu", MATRIX, lambda q, r, dim: packing.hamming_matrix_mxu(q, r, dim))
-register("kernel_vpu", MATRIX, _kernel_vpu)
-register("kernel_mxu", MATRIX, _kernel_mxu)
-register("fused", FUSED, _fused_pallas)
-register("fused_mxu", FUSED, _fused_mxu, tile_name="kernel_mxu")
+register("kernel_vpu", MATRIX, _kernel_vpu, pallas=True)
+register("kernel_mxu", MATRIX, _kernel_mxu, pallas=True)
+register("fused", FUSED, _fused_pallas, pallas=True)
+register("fused_mxu", FUSED, _fused_mxu, tile_name="kernel_mxu", pallas=True)
 register("fused_xla", FUSED, _fused_xla)
 
 
-def hamming_tile_fn(name: str) -> Callable:
-    """Plain ``(q_hvs, r_hvs, dim) -> (Qb, Rk) hamming`` tile for ``name``.
+def tile_backend(name: str) -> Backend:
+    """The matrix backend whose plain ``fn(q_hvs, r_hvs, dim) -> (Qb, Rk)
+    hamming`` tile serves ``name``'s prefix/rescore stages.
 
     The dimension cascade's prefix scan and survivor rescore need a raw
     Hamming tile at arbitrary word widths. Matrix backends already have
@@ -166,10 +199,10 @@ def hamming_tile_fn(name: str) -> Callable:
     """
     be = get(name)
     if be.kind == MATRIX:
-        return be.fn
+        return be
     if be.tile_name is not None:
-        return get(be.tile_name).fn
-    return _REGISTRY["vpu"].fn
+        return get(be.tile_name)
+    return _REGISTRY["vpu"]
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +310,7 @@ _declare("search:fused_xla", "peak_intermediate",
 # — the scatter target and the (nqb, rk) flag/index carriers are the extra
 # non-tile intermediates); ``rescore:<be>`` is one stage-B exact rescore
 # over an rk = survivor-bucket candidate set at full width. Fused backends
-# route both stages through their tile sibling (see ``hamming_tile_fn``):
+# route both stages through their tile sibling (see ``tile_backend``):
 # fused_mxu runs them on the kernel_mxu tile, the rest fall back to the
 # packed-VPU tile — each declared bound is its tile fn's bound.
 

@@ -52,7 +52,12 @@ import numpy as np
 from repro.core import backends as backends_mod
 from repro.core.blocking import PAD_PMZ, ReferenceDB
 from repro.kernels.topk import select_topk as _select_topk
+from repro.obs.metrics import Metrics
 from repro.obs.trace import span
+
+# Program counters of the resident search: ``lowering_pallas`` /
+# ``lowering_xla`` count searches by how their Hamming tiles were computed.
+METRICS = Metrics()
 
 # Charge multiplier for building monotonic (charge, pmz) sort keys. PMZ values
 # are clipped below this, so keys from different charges never interleave.
@@ -120,21 +125,41 @@ def _find_topk_dual(sims, dpmz, q_pmz, q_charge, r_charge, r_pmz,
 # ---------------------------------------------------------------------------
 
 
+def scan_lowering(db: ReferenceDB, p: SearchParams) -> str:
+    """``"pallas"`` where a Pallas kernel computes a search's Hamming tiles,
+    else ``"xla"``: what the ``search.kernel`` span and the ``METRICS``
+    counters record for each search."""
+    if p.prefix_words:
+        pallas = backends_mod.tile_backend(p.backend).pallas
+    else:
+        be = backends_mod.get(p.backend)
+        pallas = be.pallas or be.scans_in_place(db.max_r, db.n_words)
+    return "pallas" if pallas else "xla"
+
+
+def _rows(db: ReferenceDB, start_row, rk: int):
+    return jax.lax.dynamic_slice(db.hvs, (start_row, 0), (rk, db.n_words))
+
+
 def _block_body(db: ReferenceDB, dim: int, p: SearchParams,
                 q_hvs, q_pmz, q_charge, start_row):
     """Scan k_blocks*max_r contiguous reference rows for one query block."""
     rk = (p.k_blocks if not p.exhaustive else db.n_blocks) * db.max_r
-    r_hvs = jax.lax.dynamic_slice(db.hvs, (start_row, 0), (rk, db.n_words))
     r_pmz = jax.lax.dynamic_slice(db.pmz, (start_row,), (rk,))
     r_charge = jax.lax.dynamic_slice(db.charge, (start_row,), (rk,))
 
     be = backends_mod.get(p.backend)
     if be.kind == backends_mod.FUSED:
         std_b, std_a, open_b, open_a = be.fn(
-            q_hvs, r_hvs, q_pmz, r_pmz, q_charge, r_charge, dim=dim,
-            ppm_tol=p.ppm_tol, open_tol_da=p.open_tol_da, k=p.top_k)
+            q_hvs, _rows(db, start_row, rk), q_pmz, r_pmz, q_charge,
+            r_charge, dim=dim, ppm_tol=p.ppm_tol, open_tol_da=p.open_tol_da,
+            k=p.top_k)
     else:
-        ham = be.fn(q_hvs, r_hvs, dim)
+        if be.scans_in_place(db.max_r, db.n_words):
+            # The kernel reads the rows where they lie: no (rk, W) slice.
+            ham = be.scan(q_hvs, db.hvs, start_row, rk)
+        else:
+            ham = be.fn(q_hvs, _rows(db, start_row, rk), dim)
         sims = dim - ham
         dpmz = jnp.abs(q_pmz[:, None] - r_pmz[None, :])
         std_b, std_a, open_b, open_a = _find_topk_dual(
@@ -247,7 +272,7 @@ def _prefix_flags(db: ReferenceDB, q_hvs_p, q_pmz, q_charge, thr_std,
     QB = p.q_block
     nqb = q_hvs_p.shape[0] // QB
     rk = (p.k_blocks if not p.exhaustive else db.n_blocks) * db.max_r
-    tile = backends_mod.hamming_tile_fn(p.backend)
+    tile = backends_mod.tile_backend(p.backend).fn
     bkey = _block_keys(db)
 
     def one_qblock(args):
@@ -291,7 +316,7 @@ def _rescore_rows_padded(r_hvs, r_rows, r_pmz, r_charge, q_hvs, q_pmz,
     QB = p.q_block
     nqb = q_hvs.shape[0] // QB
     S = r_rows.shape[0]
-    tile = backends_mod.hamming_tile_fn(p.backend)
+    tile = backends_mod.tile_backend(p.backend).fn
 
     def one_qblock(args):
         qh, qp, qc = args
@@ -574,7 +599,9 @@ def oms_search(db: ReferenceDB, q_hvs: jax.Array, q_pmz: jax.Array,
     # Padding queries keep their charge (so the block is charge-pure) but are
     # discarded on output.
 
-    with span("search.kernel"):
+    lowering = scan_lowering(db, params)
+    METRICS.counter(f"lowering_{lowering}").inc()
+    with span("search.kernel", lowering=lowering):
         if params.prefix_words:
             if row_pmz_np is None:
                 row_pmz_np = np.asarray(db.pmz)

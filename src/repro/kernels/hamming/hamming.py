@@ -11,9 +11,11 @@ This is the TPU-native port of RapidOMS's FPGA search kernel:
   parallel find_max_score (std + open)  fused dual-window running argmax
                                         accumulated across the ref-block grid
 
-Two kernels:
+Three kernels:
   * ``hamming_matrix_kernel`` — all-pairs Hamming tile (building block,
     validated against the oracle over shape/dtype sweeps);
+  * ``scan_tile_kernel`` — the ``vpu`` backend's blocked-scan tile on TPU:
+    one query block against library rows read in place, rows on lanes;
   * ``fused_search_kernel`` — the full paper kernel: Hamming + PMZ windows +
     dual running *top-k* winners (k static, default 1), one pass over the
     reference stream, no (Q, R) score matrix ever materialised in HBM. The
@@ -40,10 +42,13 @@ construction (same property the paper gets from its sequential block stream).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.topk import merge_topk, select_topk
 
@@ -92,6 +97,143 @@ def hamming_matrix_pallas(q: jax.Array, r: jax.Array, *, q_tile: int = 16,
         out_shape=jax.ShapeDtypeStruct((Q, R), jnp.int32),
         interpret=interpret,
     )(q, r)
+
+
+# ---------------------------------------------------------------------------
+# In-place scan step kernel (the vpu backend's blocked-scan tile on TPU)
+# ---------------------------------------------------------------------------
+#
+# One query block against a contiguous run of rows of the resident library,
+# read where they lie: the first row block arrives as a scalar-prefetch
+# operand and the BlockSpec index map DMAs each grid step's rows straight
+# from the library array, so no (rk, W) slice is ever materialised. Each row
+# block is transposed once to word-major (W, SCAN_ROWS) — rows on lanes — in
+# VMEM and shared by all queries of the block. A vreg then holds 8 words of
+# 128 rows; XORed with the same 8 words of a query (broadcast along the
+# lanes once per call, in VMEM), its popcounts add up elementwise over the
+# W / 8 word groups, and only the last 8-sublane sum remains: nothing
+# crosses lanes. The loops are register-blocked: SCAN_Q_GROUP queries x
+# SCAN_CHUNK_GROUP 128-row chunks keep their accumulators in vregs, so each
+# loaded vreg serves several.
+
+SCAN_ROWS = 1024        # rows per row block; start row and length are multiples
+SCAN_BLOCKS_PER_STEP = 4
+SCAN_Q_GROUP = 2
+SCAN_CHUNK_GROUP = 8
+
+
+def scan_tile_kernel(start_ref, q_ref, r_ref, out_ref, qb_ref, rt_ref,
+                     row_ref, *, n_blocks: int, n_sub: int, lib_blocks: int,
+                     q_group: int):
+    """One grid step: ``n_sub`` row blocks of the run against the query
+    block. ``q_ref`` is the (QT, W) int32 query block; ``r_ref`` holds the
+    step's (n_sub * SCAN_ROWS, W) rows, read from ``step_first_block`` (see
+    ``scan_tile_pallas``); ``out_ref`` is the step's (QT, n_sub *
+    SCAN_ROWS) int32 output tile. VMEM scratch: ``qb_ref`` (QT, W, 128)
+    holds each query word broadcast along the lanes, filled at the first
+    step; ``rt_ref`` (W, SCAN_ROWS) holds a row block word-major;
+    ``row_ref`` (QT, 1, SCAN_ROWS) its distances, one query per leading
+    index (a store at a traced query row of ``out_ref`` itself would be
+    unaligned)."""
+    nq, w = q_ref.shape
+    first = pl.program_id(0) * n_sub
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        for q in range(nq):
+            qb_ref[q] = lax.broadcast_in_dim(q_ref[q:q + 1, :], (128, w),
+                                             (0, 1)).T
+
+    # The last step's rows may have been read from an earlier block so that
+    # they stay inside the library: skip that many blocks of the buffer.
+    shift = start_ref[0] + first - step_first_block(
+        start_ref[0], first, n_sub, lib_blocks)
+
+    # The body below unrolls into about a thousand ops, traced in every
+    # process that compiles or loads the scan: lax, not jnp (a jnp call
+    # traces a nested jit), and one traced copy for all query groups.
+    def group(q0, base):
+        cols = [pl.ds(pl.multiple_of(base + 128 * c, 128), 128)
+                for c in range(SCAN_CHUNK_GROUP)]
+        acc = {}
+        for g in range(0, w, 8):
+            rows = [rt_ref[g:g + 8, col] for col in cols]
+            for qq in range(q_group):
+                qv = qb_ref[q0 + qq, g:g + 8, :]
+                for c, r in enumerate(rows):
+                    x = lax.population_count(lax.bitwise_xor(r, qv))
+                    acc[qq, c] = x if g == 0 else lax.add(acc[qq, c], x)
+        for (qq, c), a in acc.items():
+            row_ref[q0 + qq, :, cols[c]] = lax.reduce_sum(a, (0,))[None, :]
+
+    def row_block(i, carry):
+        @pl.when(first + i < n_blocks)
+        def _():
+            off = pl.multiple_of((shift + i) * SCAN_ROWS, SCAN_ROWS)
+            for c in range(0, SCAN_ROWS, 128):
+                rt_ref[:, c:c + 128] = pltpu.bitcast(
+                    r_ref[pl.ds(off + c, 128), :], jnp.int32).T
+            n_cg = SCAN_ROWS // (128 * SCAN_CHUNK_GROUP)
+
+            def chunks(t, carry):
+                group((t // n_cg) * q_group, pl.multiple_of(
+                    (t % n_cg) * 128 * SCAN_CHUNK_GROUP, 128))
+                return carry
+
+            lax.fori_loop(0, nq // q_group * n_cg, chunks, 0)
+            block = pl.ds(pl.multiple_of(i * SCAN_ROWS, SCAN_ROWS), SCAN_ROWS)
+            for q in range(nq):
+                out_ref[q:q + 1, block] = row_ref[q]
+        return carry
+
+    lax.fori_loop(0, n_sub, row_block, 0)
+
+
+def step_first_block(start_block, first, n_sub: int, lib_blocks: int):
+    """Library block where a grid step's rows are read from: the step's
+    first block of the run, moved back so that its ``n_sub`` blocks end
+    inside the library."""
+    return jnp.minimum(start_block + first, lib_blocks - n_sub)
+
+
+def scan_tile_pallas(q: jax.Array, hvs: jax.Array, start_block: jax.Array, *,
+                     n_blocks: int, interpret: bool = True) -> jax.Array:
+    """q (QT, W) uint32 against rows ``[start_block, start_block + n_blocks)
+    * SCAN_ROWS`` of ``hvs`` (R, W) uint32 -> (QT, n_blocks * SCAN_ROWS)
+    int32 Hamming distances. ``start_block`` is a traced int32 scalar; the
+    caller guarantees the rows lie inside ``hvs``, R % SCAN_ROWS == 0 and
+    W % 8 == 0."""
+    nq, w = q.shape
+    lib_blocks = hvs.shape[0] // SCAN_ROWS
+    n_sub = min(SCAN_BLOCKS_PER_STEP, n_blocks, lib_blocks)
+    steps = -(-n_blocks // n_sub)
+    q_group = math.gcd(SCAN_Q_GROUP, nq)
+
+    def rows_map(j, start):
+        return (step_first_block(start[0], j * n_sub, n_sub, lib_blocks)
+                * SCAN_ROWS, 0)
+
+    out = pl.pallas_call(
+        functools.partial(scan_tile_kernel, n_blocks=n_blocks, n_sub=n_sub,
+                          lib_blocks=lib_blocks, q_group=q_group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(steps,),
+            in_specs=[pl.BlockSpec((nq, w), lambda j, start: (0, 0)),
+                      pl.BlockSpec((pl.Element(n_sub * SCAN_ROWS),
+                                    pl.Element(w)), rows_map)],
+            out_specs=pl.BlockSpec((nq, n_sub * SCAN_ROWS),
+                                   lambda j, start: (0, j)),
+            scratch_shapes=[pltpu.VMEM((nq, w, 128), jnp.int32),
+                            pltpu.VMEM((w, SCAN_ROWS), jnp.int32),
+                            pltpu.VMEM((nq, 1, SCAN_ROWS), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((nq, steps * n_sub * SCAN_ROWS),
+                                       jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(start_block.astype(jnp.int32).reshape(1),
+      lax.bitcast_convert_type(q, jnp.int32), hvs)
+    return out[:, :n_blocks * SCAN_ROWS]
 
 
 # ---------------------------------------------------------------------------

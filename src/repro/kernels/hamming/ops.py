@@ -77,3 +77,22 @@ def fused_search(q_hvs, r_hvs, q_pmz, r_pmz, q_charge, r_charge, *, dim: int,
         open_tol_da=open_tol_da, q_tile=q_tile, r_tile=rt,
         word_tile=wt, pad_pmz=PAD_PMZ, interpret=interpret)
     return std_sim[:Q], std_idx[:Q], open_sim[:Q], open_idx[:Q]
+
+
+def scan_tile_fits(max_r: int, n_words: int) -> bool:
+    """Whether the in-place scan kernel takes a library blocked in ``max_r``
+    rows: every scanned run then starts and ends on a kernel row block, and
+    a row is whole 128-word lane chunks (``dim`` a multiple of 4096)."""
+    return max_r % _k.SCAN_ROWS == 0 and n_words % 128 == 0
+
+
+@partial(jax.jit, static_argnames=("rk", "interpret"))
+def scan_tile(q, hvs, start_row, *, rk: int, interpret: bool | None = None):
+    """(QT, W) queries against rows ``[start_row, start_row + rk)`` of the
+    library ``hvs``, read in place -> (QT, rk) int32 Hamming distances.
+    ``start_row`` (traced) and ``rk`` are multiples of ``SCAN_ROWS``."""
+    if interpret is None:
+        interpret = interpret_default()
+    return _k.scan_tile_pallas(q, hvs, start_row // _k.SCAN_ROWS,
+                               n_blocks=rk // _k.SCAN_ROWS,
+                               interpret=interpret)

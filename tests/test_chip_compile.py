@@ -12,9 +12,11 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.kernels
 from repro.core.blocking import ReferenceDB
 from repro.core.search import SearchParams, _search_sorted_padded
 from repro.kernels.hamming import ops as hops
@@ -25,11 +27,13 @@ W = DIM // 32
 Q = 16                      # OMSConfig.q_block: queries per kernel call
 R = 40 * 4096               # ~k_blocks x max_r rows scanned per query block
 IPRG_ROWS = 2_322_432       # 2 x 1.16M iPRG2012 rows, padded to 4096-blocks
+HEK_ROWS = 6_001_664        # 2 x 3M HEK293 rows, padded to 1024-blocks
 HBM_BYTES = 16e9            # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """The four devices of a described v5e:2x2."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.experimental import topologies
 
@@ -43,9 +47,14 @@ def one_chip():
                                                 topology_name="v5e:2x2")
         except Exception as e:
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo.devices
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e[0])
 
 
 def _spec(sharding, shape, dtype):
@@ -104,4 +113,94 @@ def test_search_compiles_at_iprg2012_rows(one_chip):
         db, s((qp, W), jnp.uint32), s((qp,), jnp.float32),
         s((qp,), jnp.int32),
         params=SearchParams(k_blocks=40, backend="vpu"), dim=DIM).compile()
+    _fits_one_chip(compiled)
+
+
+@pytest.fixture
+def tpu_platform(monkeypatch):
+    """Steer the platform check that picks the vpu scan step's lowering: the
+    CPU here would choose the XLA tile. The lowering is decided when a
+    jitted program is traced, so JAX's caches are cleared on both sides."""
+    not_cpu = lambda: False  # noqa: E731
+    monkeypatch.setattr(repro.kernels, "interpret_default", not_cpu)
+    monkeypatch.setattr(hops, "interpret_default", not_cpu)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _library(s, rows, max_r=1024):
+    nb = rows // max_r
+    return ReferenceDB(
+        hvs=s((rows, W), jnp.uint32), pmz=s((rows,), jnp.float32),
+        charge=s((rows,), jnp.int32), is_decoy=s((rows,), jnp.bool_),
+        orig_idx=s((rows,), jnp.int32),
+        block_min=s((nb,), jnp.float32), block_max=s((nb,), jnp.float32),
+        block_charge=s((nb,), jnp.int32), max_r=max_r)
+
+
+def _queries(s, n):
+    return s((n, W), jnp.uint32), s((n,), jnp.float32), s((n,), jnp.int32)
+
+
+@pytest.mark.parametrize("rows,k_blocks", [(IPRG_ROWS, 129), (HEK_ROWS, 323)],
+                         ids=["iprg2012", "hek293"])
+def test_vpu_search_runs_the_scan_kernel(one_chip, tpu_platform, rows,
+                                         k_blocks):
+    """The vpu blocked scan at the benchmark's row shapes (``max_r`` 1024)
+    holds the in-place scan kernel and fits one chip."""
+    s = functools.partial(_spec, one_chip)
+    compiled = _search_sorted_padded.lower(
+        _library(s, rows), *_queries(s, 2048 + 2 * Q),
+        params=SearchParams(k_blocks=k_blocks, backend="vpu"),
+        dim=DIM).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("slab_rows", [1 << 16, 1 << 18],
+                         ids=["capped_to_slab", "default_slab"])
+def test_vpu_slab_search_runs_the_scan_kernel(one_chip, tpu_platform,
+                                              slab_rows):
+    """The streamed engine's slab step (``serve/engine.py``): one slab of
+    the library, the iPRG2012 plan's ``k_blocks`` 129 capped to the slab's
+    blocks, a serve-sized query batch."""
+    s = functools.partial(_spec, one_chip)
+    compiled = _search_sorted_padded.lower(
+        _library(s, slab_rows), *_queries(s, 2 * Q),
+        params=SearchParams(k_blocks=min(129, slab_rows // 1024),
+                            backend="vpu"),
+        dim=DIM).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+def test_sharded_vpu_search_runs_the_scan_kernel(v5e, tpu_platform):
+    """``distributed.collectives.sharded_search`` over the four chips of a
+    v5e:2x2: the iPRG2012 library split into four slabs, each chip's scan
+    through the in-place kernel inside ``shard_map``."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.distributed.collectives import sharded_search
+
+    mesh = Mesh(np.asarray(v5e).reshape(1, 4), ("data", "model"))
+
+    def by_row(shape, dtype):
+        spec = P("model", *[None] * (len(shape) - 1))
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def replicated(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+
+    params = SearchParams(k_blocks=129, backend="vpu")
+
+    def search(db, qh, qp, qc):
+        return sharded_search(db, qh, qp, qc, params, dim=DIM, mesh=mesh)[0]
+
+    compiled = jax.jit(search).lower(
+        _library(by_row, IPRG_ROWS), *_queries(replicated, 2048 + 2 * Q)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     _fits_one_chip(compiled)

@@ -4,8 +4,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.blocking import build_reference_db
 from repro.core.encoding import (PreprocessedSpectra, encode_spectra,
                                  make_codebooks)
+from repro.core.packing import hamming_matrix_packed
+from repro.core.search import SearchParams, _search_sorted_padded, plan_search
+from repro.kernels.hamming import hamming as hkern
 from repro.kernels.hamming import ops as hops
 from repro.kernels.hamming import ref as href
 from repro.kernels.hamming_mxu import ops as mops
@@ -90,6 +94,72 @@ def test_fused_search_mxu_kernel_sweep(Q, R, W, k):
     g = mops.fused_search(q, r, qp, rp, qc, rc, dim=W * 32, k=k)
     for name, a, b in zip(("std_sim", "std_idx", "open_sim", "open_idx"), o, g):
         assert a.shape == (Q, k), name
+        assert (np.asarray(a) == np.asarray(b)).all(), name
+
+
+def _bits(key, n, w):
+    return jax.random.bits(key, (n, w), jnp.uint32)
+
+
+@pytest.mark.parametrize("W", [16, 128])
+@pytest.mark.parametrize("start_block,n_blocks", [(0, 1), (5, 1), (0, 6),
+                                                  (1, 5)],
+                         ids=["first", "last", "all", "to_last"])
+def test_scan_tile_kernel_in_place(W, start_block, n_blocks):
+    """The vpu scan step's kernel reads a run of library rows in place and
+    equals the XLA tile bit for bit: one row block and many (a partial last
+    grid step included), runs at the library's first and last block, and
+    words whose popcount is 0 or 32."""
+    sr = hkern.SCAN_ROWS
+    k1, k2 = jax.random.split(jax.random.PRNGKey(W + start_block))
+    hvs = _bits(k1, 6 * sr, W).at[::7].set(0).at[3::7].set(0xFFFFFFFF)
+    q = (_bits(k2, 16, W).at[0].set(0).at[1].set(0xFFFFFFFF)
+         .at[2, ::2].set(0).at[3, 1::2].set(0xFFFFFFFF))
+    got = hops.scan_tile(q, hvs, jnp.int32(start_block * sr),
+                         rk=n_blocks * sr, interpret=True)
+    want = hamming_matrix_packed(
+        q, hvs[start_block * sr:(start_block + n_blocks) * sr])
+    assert got.shape == want.shape == (16, n_blocks * sr)
+    assert (np.asarray(got) == np.asarray(want)).all()
+
+
+def _tied_library(n_rows=5000, n_queries=32, dim=512):
+    """A library drawn from four distinct hypervectors, so that nearly
+    every query has many equal-scoring candidates in its windows."""
+    rng = np.random.default_rng(11)
+    pool = np.asarray(_bits(jax.random.PRNGKey(11), 4, dim // 32))
+    hvs = pool[rng.integers(0, 4, n_rows)]
+    pmz = rng.uniform(400.0, 1400.0, n_rows).astype(np.float32)
+    charge = np.where(np.arange(n_rows) % 2 == 0, 2, 3).astype(np.int32)
+    db = build_reference_db(hvs, pmz, charge, np.zeros(n_rows, bool),
+                            max_r=hkern.SCAN_ROWS)
+    q_pmz = np.sort(rng.uniform(600.0, 1200.0, n_queries)).astype(np.float32)
+    q_charge = np.full(n_queries, 2, np.int32)
+    q_hvs = pool[rng.integers(0, 4, n_queries)]
+    q_hvs[::3] ^= np.uint32(1 << 7)          # one bit off a pool vector
+    return db, jnp.asarray(q_hvs), jnp.asarray(q_pmz), jnp.asarray(q_charge)
+
+
+@pytest.mark.parametrize("top_k", [1, 4])
+def test_scan_kernel_search_equals_xla_with_ties(request, top_k):
+    """The blocked scan through the in-place kernel returns exactly what the
+    XLA lowering returns, tie order (similarity desc, row asc) included."""
+    db, qh, qp, qc = _tied_library()
+    params = SearchParams(q_block=16, top_k=top_k, k_blocks=plan_search(
+        db, np.asarray(qp), np.asarray(qc), open_tol_da=75.0, q_block=16))
+    def scan(d, a, b, c):
+        return _search_sorted_padded(d, a, b, c, params=params, dim=512)
+
+    assert "pallas_call" not in str(jax.make_jaxpr(scan)(db, qh, qp, qc))
+    want = scan(db, qh, qp, qc)
+    request.getfixturevalue("force_scan_kernel")
+    assert "pallas_call" in str(jax.make_jaxpr(scan)(db, qh, qp, qc))
+    got = scan(db, qh, qp, qc)
+    sims = np.asarray(want[2])
+    assert (sims[:, :top_k] >= 0).all()
+    assert (np.diff(sims, axis=1) == 0).any() or top_k == 1
+    for name, a, b in zip(("std_sim", "std_row", "open_sim", "open_row"),
+                          want, got):
         assert (np.asarray(a) == np.asarray(b)).all(), name
 
 

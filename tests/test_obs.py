@@ -390,3 +390,36 @@ def test_profiled_search_writes_every_span_with_its_ids(tmp_path):
         p_name, _, p_trace, p0, p1 = profiled[parent]
         assert p_name == SEARCH_SPANS[name] and trace == p_trace
         assert p0 <= t0 and t1 <= p1
+
+
+@pytest.mark.parametrize("forced", [False, True], ids=["cpu", "forced"])
+def test_search_kernel_span_and_counter_name_the_lowering(request, forced):
+    """Each search records how its Hamming tiles were computed: ``xla`` on
+    the CPU, ``pallas`` where the vpu scan step takes its in-place kernel
+    (forced here through the test's hook), with the same answers."""
+    from repro.core import OMSConfig, OMSPipeline, search
+    from repro.data.spectra import LibraryConfig, make_dataset
+
+    cfg = OMSConfig(dim=256, n_levels=8, max_r=1024, q_block=16)
+    ds = make_dataset(LibraryConfig(n_refs=300, n_queries=16, seed=7))
+    pipe = OMSPipeline(cfg, ds.refs)
+    hvs, qp, qc = pipe.encode_queries(ds.queries)
+    plain = pipe.search_encoded(hvs, qp, qc).result
+    if forced:
+        request.getfixturevalue("force_scan_kernel")
+    want = "pallas" if forced else "xla"
+    before = search.METRICS.snapshot()
+    t = install(Tracer())
+    try:
+        got = pipe.search_encoded(hvs, qp, qc).result
+    finally:
+        uninstall()
+    after = search.METRICS.snapshot()
+    (ev,) = [e for e in t.events() if e.name == "search.kernel"]
+    assert ev.attrs["lowering"] == want
+    for name in ("lowering_pallas", "lowering_xla"):
+        grew = after.get(name, 0) - before.get(name, 0)
+        assert grew == (1 if name == f"lowering_{want}" else 0), name
+    for f in plain._fields:
+        assert (np.asarray(getattr(plain, f))
+                == np.asarray(getattr(got, f))).all(), f
