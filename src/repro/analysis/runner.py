@@ -248,24 +248,25 @@ def _prefix_results(fx: _Fixture) -> dict[str, dict[str, list]]:
     return out
 
 
-def _merge_step_results(fx: _Fixture) -> list[C.ContractResult]:
-    """The streamed path's cross-slab fold (offset + merge_topk) is part of
-    the slab step's device program — same contracts, tiny trace."""
-    from repro.serve.engine import _merge_partials, _offset_rows
+def _slab_step_results(fx: _Fixture) -> list[C.ContractResult]:
+    """The streamed path's whole slab step (q-block slice, scan, offset and
+    fold into the running best): no host transfer, no 64-bit promotion."""
+    from repro.serve.engine import _empty_run, _search_sorted_padded_slab
+    from repro.serve.slabs import slab_arrays
 
     sm = fx.sm
-    Q = fx.qp_np.shape[0]
-    part = tuple(np.zeros((Q, sm.top_k), np.int32) for _ in range(4))
-    j1 = jax.make_jaxpr(
-        lambda *a: _offset_rows(*a, np.int32(64)))(*part)
-    j2 = jax.make_jaxpr(
-        lambda r, p: _merge_partials(r, p, sm.top_k))(part, part)
-    out = []
-    for j in (j1, j2):
-        out.append(C.check_no_host_transfer(j, target="serve:slab_step"))
-        out.append(C.check_dtype_stability(j, target="serve:slab_step",
-                                           hv_words=sm.n_words))
-    return out
+    eng = fx.streamed.engine
+    qh, qp, qc = fx.padded_queries()
+    p = fx.resident.search_params(fx.qp_np, fx.qc_np)
+    p = p._replace(k_blocks=min(p.k_blocks, eng.plan.slab_blocks))
+    run = _empty_run(qh.shape[0], sm.top_k, None)
+    j = jax.make_jaxpr(
+        lambda r, d, a, b, c: _search_sorted_padded_slab(
+            r, d, a, b, c, np.int32(0), np.int32(64), params=p, dim=sm.dim,
+            n_qb=1))(run, slab_arrays(eng.layout, 0, eng.plan), qh, qp, qc)
+    return [C.check_no_host_transfer(j, target="serve:slab_step"),
+            C.check_dtype_stability(j, target="serve:slab_step",
+                                    hv_words=sm.n_words)]
 
 
 def _obs_results(fx: _Fixture) -> list[C.ContractResult]:
@@ -351,7 +352,8 @@ def _recompile_results(fx: _Fixture) -> dict[str, list[C.ContractResult]]:
         ("search._search_sorted_padded", search_mod._search_sorted_padded),
         ("search._prefix_flags", search_mod._prefix_flags),
         ("search._rescore_rows_padded", search_mod._rescore_rows_padded),
-        ("engine._offset_rows", engine_mod._offset_rows),
+        ("engine._search_sorted_padded_slab",
+         engine_mod._search_sorted_padded_slab),
         ("engine._merge_partials", engine_mod._merge_partials),
         ("encode._preprocess_jit", encode_backends._preprocess_jit),
         ("encode._encode_batched_jit", encode_backends._encode_batched_jit),
@@ -391,7 +393,7 @@ def run(sm: SmokeShapes | None = None, *,
         enc = _encode_results(fx)
         srch = _search_results(fx)
         pref = _prefix_results(fx)
-        merge_res = _merge_step_results(fx)
+        slab_res = _slab_step_results(fx)
         obs_res = _obs_results(fx)
         reco = _recompile_results(fx) if with_recompile else {}
     finally:
@@ -403,7 +405,7 @@ def run(sm: SmokeShapes | None = None, *,
             cascade = stage == "narrow"
             results = list(enc[e]) + list(srch[(be, path, stage)])
             if path == "streamed":
-                results += merge_res
+                results += slab_res
             if not cascade and be in reco:
                 results += [r for r in reco[be]
                             if f"[{path}:" in r.target]

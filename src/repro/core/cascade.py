@@ -6,7 +6,7 @@ identification as a cascade: a cheap **narrow** pass (open window shrunk to
 blocks) identifies the unmodified spectra first, and only the survivors pay
 for the expensive **open** scan over the full ±``open_tol_da`` window. On
 the streaming serve engine the same shrinkage prunes at slab granularity:
-stage 1's ``slabs_touched`` windows are tiny, so far fewer slabs stream.
+stage 1's windows (``slab_qblocks``) are tiny, so far fewer slabs stream.
 
 Orchestration lives here; the stages themselves are ordinary searches run
 through a caller-supplied ``run_stage(sel, narrow=...)`` closure (the

@@ -100,10 +100,11 @@ def streaming_engine_for_mesh(store_or_layout, mesh: Mesh, *, max_r: int,
     StreamingEngine` whose slab stream is dealt round-robin across the
     ``model``-axis devices — the multi-SmartSSD scale-out with the library
     *streamed* instead of slab-resident (`sharded_db_from_store`). Each
-    device scans its slabs independently (async dispatch overlaps them);
-    partial winners merge on the first model-axis device in ascending slab
-    order — the same tie discipline as ``_merge_best`` — so results stay
-    bit-identical to the single-device engine and to a resident search.
+    device scans its slabs independently (async dispatch overlaps them) and
+    keeps the running best of its own slabs; at the end the devices' bests
+    merge on the first model-axis device by (sim desc, row asc) — the same
+    tie discipline as ``_merge_best`` — so results stay bit-identical to
+    the single-device engine and to a resident search.
     """
     from repro.serve import StreamingEngine
     devs = np.asarray(mesh.devices)

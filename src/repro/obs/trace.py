@@ -1,8 +1,8 @@
 """Structured tracing: spans, a thread-safe ring buffer, Perfetto export.
 
 The serve hot path is instrumented with :func:`span` context managers at
-the real seams — query encode, window planning, per-slab fetch/search/
-merge, micro-batch dispatch — all HOST-side, strictly *around* the jit
+the real seams — query encode, window planning, per-slab gather/wait/
+search/merge, micro-batch dispatch — all HOST-side, strictly *around* the jit
 boundaries. Spans never reach inside a traced function: the analyzer's
 ``trace_transparency`` contract machine-checks that installing a tracer
 leaves every hot jaxpr byte-identical (and therefore adds no host-transfer
@@ -16,8 +16,9 @@ that record ``(name, t_start_ns, t_end_ns, attrs)`` into a bounded ring
 buffer (old events are evicted, never the serve loop blocked).
 
 Every live span carries three ids: its own ``span_id``, the ``parent_id``
-of the innermost span open on the same thread when it began (0 for a
-root), and the ``trace_id`` of its root span. The same span also opens a
+of the innermost span open on the same thread when it began, or of the
+span passed as ``parent`` (0 for a root), and the ``trace_id`` of its root
+span. The same span also opens a
 ``jax.profiler.TraceAnnotation`` of its name with those ids as arguments,
 so under a profiler session each span lands in the profiler's host trace,
 on the device trace's clock, where a reader picks program spans out by
@@ -187,13 +188,14 @@ class _Span:
     record on exit. ``add(**attrs)`` attaches facts learned mid-span (bytes
     fetched, rows survived)."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_ann", "_stack",
-                 "span_id", "parent_id", "trace_id")
+    __slots__ = ("_tracer", "_name", "_attrs", "_parent", "_t0", "_ann",
+                 "_stack", "span_id", "parent_id", "trace_id")
 
-    def __init__(self, tracer: Tracer, name: str, attrs: dict):
+    def __init__(self, tracer: Tracer, name: str, attrs: dict, parent=None):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
+        self._parent = parent
 
     def add(self, **attrs) -> None:
         self._attrs.update(attrs)
@@ -202,8 +204,10 @@ class _Span:
         t = self._tracer
         self._stack = stack = t._open.stack
         self.span_id = next(t._ids)
-        if stack:
-            self.parent_id, self.trace_id = stack[-1].span_id, stack[-1].trace_id
+        up = self._parent if isinstance(self._parent, _Span) else (
+            stack[-1] if stack else None)
+        if up is not None:
+            self.parent_id, self.trace_id = up.span_id, up.trace_id
         else:
             self.parent_id, self.trace_id = 0, self.span_id
         stack.append(self)
@@ -243,13 +247,16 @@ NOOP_SPAN = _NoopSpan()
 _tracer: Tracer | None = None
 
 
-def span(name: str, **attrs):
+def span(name: str, *, parent=None, **attrs):
     """Context manager timing one named stage. With no tracer installed
-    this returns the shared no-op singleton (the zero-overhead path)."""
+    this returns the shared no-op singleton (the zero-overhead path).
+    ``parent``, a live span of another thread, makes this span its child
+    (work a thread does for a span open elsewhere); by default the parent
+    is the innermost span open on this thread."""
     t = _tracer
     if t is None:
         return NOOP_SPAN
-    return _Span(t, name, attrs)
+    return _Span(t, name, attrs, parent)
 
 
 def install(tracer: Tracer) -> Tracer:

@@ -8,5 +8,5 @@ from repro.serve.engine import StreamingEngine, StreamStats, TotalStats
 from repro.serve.result_cache import ResultCache
 from repro.serve.scheduler import (DeadlineExceeded, MicroBatcher, QuerySpec,
                                    coalesce_queries)
-from repro.serve.slabs import (SlabPlan, StoreLayout, plan_slabs, slab_arrays,
-                               slabs_touched)
+from repro.serve.slabs import (SlabPlan, StoreLayout, plan_slabs,
+                               qblock_bucket, slab_arrays, slab_qblocks)

@@ -13,10 +13,12 @@ on this repro live here:
   * :func:`plan_slabs` — cuts the layout's block dimension into fixed-size
     slabs of ``slab_blocks`` whole blocks. Every slab has the same device
     shape (the tail slab is padded), so the per-slab search compiles once.
-  * :func:`slabs_touched` — intersects a coalesced query batch's open
-    precursor windows with each slab's block [min, max] ranges so the
-    streaming executor skips slabs no query touches (the paper's
-    DRAM-orchestrator pruning, lifted to slab granularity).
+  * :func:`slab_qblocks` — intersects a coalesced query batch's open
+    precursor windows with each slab's rows: the streaming executor skips
+    slabs no window meets, and in each slab it scans only the query blocks
+    whose windows meet it (the paper's DRAM-orchestrator pruning, lifted to
+    slab granularity); :func:`qblock_bucket` pads their number to a few
+    sizes, so a run compiles a bounded set of slab steps.
   * :func:`slab_arrays` — assembles slab ``s`` as a host-side
     :class:`~repro.core.blocking.ReferenceDB` ready for ``device_put``.
 
@@ -29,6 +31,9 @@ what makes the cross-slab top-k merge bit-identical to a resident scan.
 """
 from __future__ import annotations
 
+import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, NamedTuple, Sequence
 
 import numpy as np
@@ -38,6 +43,13 @@ from repro.core.blocking import (LibraryRun, ReferenceDB, block_pmz_ranges,
                                  run_sort_keys)
 
 _F32_MAX = np.float32(np.finfo(np.float32).max)
+_READERS = min(8, os.cpu_count() or 1)   # threads reading one slab's runs
+
+
+@functools.cache
+def _reader_pool() -> ThreadPoolExecutor:
+    """The process's store-reading threads, shared by every read."""
+    return ThreadPoolExecutor(_READERS, thread_name_prefix="store-read")
 
 # Sorts after every real block key in core.search's monotonic bkey space
 # (charge * _CHARGE_KEY + clipped pmz): real charges are small ints, so tail
@@ -146,34 +158,38 @@ class StoreLayout:
     def read_hv_rows(self, lo: int, hi: int,
                      n_words: int | None = None) -> np.ndarray:
         """Gather padded rows [lo, hi) of the packed HVs from the mmapped
-        runs (zeros on padding rows). Within each run the gathered rows are
-        ascending (the merge is stable), so shard reads stay sequential.
-        ``n_words`` < the full width reads only that word prefix per row —
-        the dimension cascade's stage-A scanned-bytes saving."""
-        W = self.n_words if n_words is None else n_words
-        out = np.zeros((hi - lo, W), np.uint32)
-        src = self.src_run[lo:hi]
-        rows = self.src_row[lo:hi]
-        for run in np.unique(src):
-            if run < 0:
-                continue
-            m = src == run
-            out[m] = np.asarray(self._hv_runs[run][rows[m], :W])
-        return out
+        runs (zeros on padding rows). ``n_words`` < the full width reads
+        only that word prefix per row — the dimension cascade's stage-A
+        scanned-bytes saving."""
+        return self._read(self.src_run[lo:hi], self.src_row[lo:hi], n_words)
 
     def gather_rows(self, rows_padded: np.ndarray,
                     n_words: int | None = None) -> np.ndarray:
         """Gather an ARBITRARY ascending set of padded-layout rows (the
         cascade's seed / survivor fetches). Padding rows come back zero."""
+        return self._read(self.src_run[rows_padded],
+                          self.src_row[rows_padded], n_words)
+
+    def _read(self, src: np.ndarray, rows: np.ndarray,
+              n_words: int | None) -> np.ndarray:
+        """The packed HVs of layout rows whose sources are (run ``src``, row
+        ``rows``). One fancy-index read per run, its rows ascending (the
+        merge is stable, so shard reads stay sequential); up to
+        ``_READERS`` runs are read at once on the shared reader pool, since
+        NumPy copies without the GIL."""
         W = self.n_words if n_words is None else n_words
-        out = np.zeros((rows_padded.shape[0], W), np.uint32)
-        src = self.src_run[rows_padded]
-        rows = self.src_row[rows_padded]
-        for run in np.unique(src):
-            if run < 0:
-                continue
-            m = src == run
-            out[m] = np.asarray(self._hv_runs[run][rows[m], :W])
+        out = np.empty((src.shape[0], W), np.uint32)
+        order = np.argsort(src, kind="stable")
+        segs = np.split(order, np.flatnonzero(np.diff(src[order])) + 1)
+
+        def read(seg):
+            run = src[seg[0]]
+            out[seg] = 0 if run < 0 else self._hv_runs[run][rows[seg], :W]
+
+        if len(segs) > 1:
+            list(_reader_pool().map(read, segs))
+        elif segs[0].size:
+            read(segs[0])
         return out
 
     def real_rows(self, lo: int, hi: int) -> int:
@@ -210,34 +226,86 @@ def plan_slabs(n_blocks: int, *, max_r: int, slab_rows: int) -> SlabPlan:
                     n_slabs=-(-n_blocks // slab_blocks), max_r=max_r)
 
 
-def slabs_touched(layout, q_pmz: np.ndarray, q_charge: np.ndarray, *,
-                  open_tol_da: float, plan: SlabPlan) -> np.ndarray:
-    """(n_slabs,) bool: does any query's open precursor window intersect any
-    block of the slab? A skipped slab cannot contain an in-window candidate
-    (the std ppm window is nested inside the open window), so skipping
-    preserves bit-identity with a full scan.
-    """
-    qp = np.asarray(q_pmz)
+# Widening of every host-side window test: it covers the f32 rounding of
+# the needles below and of the device's |q_pmz - r_pmz| for precursors in
+# the scan's key range (< 8192 Da), so the rows the host finds in a window
+# are a superset of those the device counts as in it.
+_WINDOW_SLACK_DA = 1e-3
+
+
+def _window_rows(layout, q_pmz: np.ndarray, q_charge: np.ndarray, *,
+                 open_tol_da: float) -> tuple[np.ndarray, np.ndarray]:
+    """Per query, the ``[lo, hi)`` layout rows of its charge whose precursor
+    lies in its open window (``lo == hi`` where none does). A charge's rows
+    are one run of whole blocks, pmz ascending with its padding rows last
+    (at the f32 maximum), so each bound is one ``searchsorted``."""
+    qp = np.asarray(q_pmz, np.float64)
     qc = np.asarray(q_charge)
-    bmin = np.asarray(layout.block_min)
-    bmax = np.asarray(layout.block_max)
+    lo = np.zeros(qp.shape, np.int64)
+    hi = np.zeros(qp.shape, np.int64)
     bch = np.asarray(layout.block_charge)
-    hit = np.zeros((layout.n_blocks,), bool)
+    tol = open_tol_da + _WINDOW_SLACK_DA
     for c in np.unique(qc):
-        blk = bch == c
-        if not blk.any():
+        blocks = np.flatnonzero(bch == c)
+        if not blocks.size:
             continue
+        r0 = int(blocks[0]) * layout.max_r
+        pm = np.asarray(layout.pmz[r0:(int(blocks[-1]) + 1) * layout.max_r])
         m = qc == c
-        lo = np.sort(qp[m] - open_tol_da)
-        hi = np.sort(qp[m] + open_tol_da)
-        # Block b intersects some window [lo_i, hi_i] iff
-        # #{i: lo_i <= bmax_b} > #{i: hi_i < bmin_b} — two searchsorteds.
-        a = np.searchsorted(lo, bmax[blk], side="right")
-        b = np.searchsorted(hi, bmin[blk], side="left")
-        hit[blk] |= a > b
-    padded = np.zeros((plan.n_slabs * plan.slab_blocks,), bool)
-    padded[:layout.n_blocks] = hit
-    return padded.reshape(plan.n_slabs, plan.slab_blocks).any(axis=1)
+        lo[m] = r0 + np.searchsorted(pm, (qp[m] - tol).astype(np.float32),
+                                     side="left")
+        hi[m] = r0 + np.searchsorted(pm, (qp[m] + tol).astype(np.float32),
+                                     side="right")
+    return lo, hi
+
+
+def _slab_cover(layout, q_pmz, q_charge, *, open_tol_da: float,
+                plan: SlabPlan) -> np.ndarray:
+    """(Q, n_slabs) bool: does slab ``s`` hold a row of query ``i``'s
+    window?"""
+    lo, hi = _window_rows(layout, q_pmz, q_charge, open_tol_da=open_tol_da)
+    s = np.arange(plan.n_slabs)
+    first = (lo // plan.slab_rows)[:, None]
+    last = ((hi - 1) // plan.slab_rows)[:, None]
+    return (hi > lo)[:, None] & (first <= s) & (s <= last)
+
+
+def slab_qblocks(layout, q_pmz: np.ndarray, q_charge: np.ndarray, *,
+                 q_block: int, open_tol_da: float,
+                 plan: SlabPlan) -> tuple[np.ndarray, np.ndarray]:
+    """Per slab, the ``[first, stop)`` range of query blocks that holds
+    every q-block with a query whose open window meets the slab
+    (``first == stop == 0`` for a slab no window meets). A slab no window
+    meets cannot contain an in-window candidate (the std ppm window is
+    nested inside the open window), so skipping it preserves bit-identity
+    with a full scan.
+
+    ``q_pmz``/``q_charge`` are the (charge, pmz)-sorted, ``q_block``-padded
+    queries the slab scan runs on. A q-block's results from a slab its
+    windows miss are all empty, so scanning only these ranges changes no
+    result; q-blocks inside a range whose windows miss the slab are harmless
+    for the same reason.
+    """
+    cover = _slab_cover(layout, q_pmz, q_charge, open_tol_da=open_tol_da,
+                        plan=plan)
+    nqb = cover.shape[0] // q_block
+    hit = cover.reshape(nqb, q_block, plan.n_slabs).any(axis=1)
+    touched = hit.any(axis=0)
+    first = np.where(touched, hit.argmax(axis=0), 0)
+    stop = np.where(touched, nqb - hit[::-1].argmax(axis=0), 0)
+    return first, stop
+
+
+def qblock_bucket(n: int, n_qblocks: int) -> int:
+    """How many q-blocks a slab step scans for ``n`` selected ones: ``n`` up
+    to 8, above that the next of eight steps per octave (9, 10, ..., 16,
+    18, 20, ..., 32, 36, ...), at most ``n_qblocks``. A step scans at most
+    1/8 more q-blocks than were selected, and a run compiles one slab step
+    per bucket it meets, a few per octave of batch sizes."""
+    if n > 8:
+        e = (n - 1).bit_length() - 4
+        n = -(-n >> e) << e
+    return min(n, n_qblocks)
 
 
 def slab_arrays(layout: StoreLayout, s: int, plan: SlabPlan,
@@ -257,8 +325,9 @@ def slab_arrays(layout: StoreLayout, s: int, plan: SlabPlan,
     rows, nb = plan.slab_rows, plan.slab_blocks
     W = layout.n_words if n_words is None else n_words
 
-    hvs = np.zeros((rows, W), np.uint32)
-    hvs[:r1 - r0] = layout.read_hv_rows(r0, r1, n_words=W)
+    hvs = layout.read_hv_rows(r0, r1, n_words=W)
+    if r1 - r0 < rows:
+        hvs = np.concatenate([hvs, np.zeros((rows - (r1 - r0), W), np.uint32)])
     pmz = np.full((rows,), _F32_MAX, np.float32)
     pmz[:r1 - r0] = layout.pmz[r0:r1]
     charge = np.full((rows,), -1, np.int32)

@@ -158,19 +158,27 @@ def test_vpu_search_runs_the_scan_kernel(one_chip, tpu_platform, rows,
     _fits_one_chip(compiled)
 
 
-@pytest.mark.parametrize("slab_rows", [1 << 16, 1 << 18],
-                         ids=["capped_to_slab", "default_slab"])
+@pytest.mark.parametrize("slab_rows,n_queries,n_qb,k_blocks", [
+    (1 << 16, 2 * Q, 2, 64), (1 << 18, 2 * Q, 2, 129),
+    (1 << 18, 47_008, 288, 256)],
+    ids=["capped_to_slab", "default_slab", "hek293_streamed"])
 def test_vpu_slab_search_runs_the_scan_kernel(one_chip, tpu_platform,
-                                              slab_rows):
-    """The streamed engine's slab step (``serve/engine.py``): one slab of
-    the library, the iPRG2012 plan's ``k_blocks`` 129 capped to the slab's
-    blocks, a serve-sized query batch."""
+                                              slab_rows, n_queries, n_qb,
+                                              k_blocks):
+    """The streamed engine's slab step (``serve/engine.py``
+    ``_search_sorted_padded_slab``): one slab of the library, the plan's
+    ``k_blocks`` capped to the slab's blocks (iPRG2012's 129; HEK293's
+    ~320), over a serve-sized query batch or the HEK293 run's 47k queries
+    with 288 of its q-blocks selected, folded into the running best."""
+    from repro.serve.engine import _search_sorted_padded_slab
+
     s = functools.partial(_spec, one_chip)
-    compiled = _search_sorted_padded.lower(
-        _library(s, slab_rows), *_queries(s, 2 * Q),
-        params=SearchParams(k_blocks=min(129, slab_rows // 1024),
-                            backend="vpu"),
-        dim=DIM).compile()
+    run = tuple(s((n_queries, 1), jnp.int32) for _ in range(4))
+    compiled = _search_sorted_padded_slab.lower(
+        run, _library(s, slab_rows), *_queries(s, n_queries),
+        s((), jnp.int32), s((), jnp.int32),
+        params=SearchParams(k_blocks=k_blocks, backend="vpu"),
+        dim=DIM, n_qb=n_qb).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits_one_chip(compiled)
 

@@ -423,3 +423,52 @@ def test_search_kernel_span_and_counter_name_the_lowering(request, forced):
     for f in plain._fields:
         assert (np.asarray(getattr(plain, f))
                 == np.asarray(getattr(got, f))).all(), f
+
+
+def test_streamed_slab_gather_runs_on_the_prefetch_thread_under_scan():
+    """The streamed search's slab reads run on the engine's prefetch thread
+    as ``serve.slab.gather`` spans, children of the call's ``serve.scan``
+    span; the loop's waits for them are ``serve.slab.wait`` spans, and each
+    slab step's ``serve.slab.search`` names its lowering. Tracing changes
+    no result byte."""
+    import tempfile
+
+    from repro.core import OMSConfig, OMSPipeline
+    from repro.data.spectra import LibraryConfig, make_dataset
+
+    cfg = OMSConfig(dim=256, n_levels=8, max_r=32, q_block=8)
+    ds = make_dataset(LibraryConfig(n_refs=200, n_queries=16, seed=7))
+    with tempfile.TemporaryDirectory() as d:
+        OMSPipeline.ingest(cfg, ds.refs, d + "/store", chunk_rows=64)
+        pipe = OMSPipeline.from_store(d + "/store", cfg, resident=False,
+                                      slab_rows=64)
+        hvs, qp, qc = pipe.encode_queries(ds.queries)
+        plain = pipe.search_encoded(hvs, qp, qc)
+        t = install(Tracer())
+        try:
+            traced = pipe.search_encoded(hvs, qp, qc)
+        finally:
+            uninstall()
+    for f in plain.result._fields:
+        a = np.asarray(getattr(plain.result, f))
+        b = np.asarray(getattr(traced.result, f))
+        assert a.tobytes() == b.tobytes(), f
+
+    evs = t.events()
+    (scan,) = [e for e in evs if e.name == "serve.scan"]
+    n = scan.attrs["slabs"]
+    assert n > 1
+    assert scan.attrs["pairs"] == pipe.engine.last_stats.scanned_pairs
+    gathers = [e for e in evs if e.name == "serve.slab.gather"]
+    assert len(gathers) == n
+    for g in gathers:
+        assert g.tid != scan.tid                   # the prefetch thread
+        assert g.parent_id == scan.span_id and g.trace_id == scan.trace_id
+        assert scan.t_start_ns <= g.t_start_ns and g.t_end_ns <= scan.t_end_ns
+    for name in ("serve.slab.wait", "serve.slab.search"):
+        evs_n = [e for e in evs if e.name == name]
+        assert len(evs_n) == n
+        assert all(e.tid == scan.tid and e.parent_id == scan.span_id
+                   for e in evs_n), name
+    assert {e.attrs["lowering"] for e in evs
+            if e.name == "serve.slab.search"} == {"xla"}

@@ -19,7 +19,7 @@ from repro.core.search import SearchParams, oms_search
 from repro.data.spectra import LibraryConfig, make_dataset
 from repro.serve import (DeadlineExceeded, MicroBatcher, QuerySpec,
                          StoreLayout, StreamingEngine, coalesce_queries,
-                         plan_slabs, slabs_touched)
+                         plan_slabs, slab_qblocks)
 
 # n_queries=40 with charges {2,3} puts a charge boundary mid-q-block — the
 # regression dataset for the plan_search charge-run-local grouping fix.
@@ -211,18 +211,21 @@ def test_query_touching_zero_slabs(setup):
 
 
 def test_slab_pruning_is_window_exact():
-    """slabs_touched marks exactly the slabs whose blocks intersect a query
+    """slab_qblocks selects exactly the slabs holding a row of a query's
     window; out-of-range and wrong-charge queries hit nothing."""
     run, *_ = _tie_fixture()
     layout = StoreLayout.from_runs([run], max_r=4)
     plan = plan_slabs(layout.n_blocks, max_r=4, slab_rows=8)
-    hit = slabs_touched(layout, np.asarray([1000.0]), np.asarray([2]),
-                        open_tol_da=0.2, plan=plan)
-    assert hit[0] and not hit[1:].any()           # only the first slab
+
+    def hit(qp, qc):
+        first, stop = slab_qblocks(layout, np.asarray(qp), np.asarray(qc),
+                                   q_block=1, open_tol_da=0.2, plan=plan)
+        return stop > first
+
+    h = hit([1000.0], [2])
+    assert h[0] and not h[1:].any()               # only the first slab
     for qp, qc in (([5000.0], [2]), ([1005.0], [3])):
-        hit = slabs_touched(layout, np.asarray(qp), np.asarray(qc),
-                            open_tol_da=0.2, plan=plan)
-        assert not hit.any()
+        assert not hit(qp, qc).any()
 
 
 # ---------------------------------------------------------------------------
