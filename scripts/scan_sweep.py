@@ -15,6 +15,13 @@ variant: the best of five runs in ms per q-block, and in cycles at 1.5 GHz
 per vreg of 1024 XORed words; and whether its tiles and per-query minima
 equal the XLA tile's bit for bit. The committed blocking is
 ``SCAN_Q_GROUP`` x ``SCAN_CHUNK_GROUP``.
+
+Then, at the committed blocking, the scan step with its dual-window top-k:
+the tile kernel followed by ``core.search._find_topk_dual`` in XLA (a
+(16, rk) tile to HBM and back) against ``scan_best_pallas``, which keeps the
+winners in VMEM; rows carry sorted precursor m/z with a charge boundary
+mid-library, each query block sits at its run's middle. Printed: ms per
+q-block of each, and whether their winners are equal bit for bit.
 """
 from __future__ import annotations
 
@@ -89,7 +96,64 @@ def main(argv=None) -> int:
               f"{best * 1.5e9 / vregs:.3f} cycles/vreg, equal={same}",
               flush=True)
     hk.SCAN_Q_GROUP, hk.SCAN_CHUNK_GROUP = committed
+    epilogue(hvs, qs, starts, kb)
     return 0
+
+
+def epilogue(hvs, qs, starts, kb: int) -> None:
+    """Time the scan step's dual-window top-k: tile + XLA against the
+    kernel that keeps the winners in VMEM."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.search import SearchParams, _find_topk_dual
+    from repro.kernels import interpret_default
+    from repro.kernels.hamming import hamming as hk
+    from repro.kernels.hamming.ops import PAD_PMZ
+
+    sr, rows, nqb = hk.SCAN_ROWS, hvs.shape[0], qs.shape[0]
+    dim, p = 32 * hvs.shape[1], SearchParams()
+    pmz = jnp.linspace(400.0, 1400.0, rows, dtype=jnp.float32)
+    charge = jnp.where(jnp.arange(rows) < rows // 2, 2, 3).astype(jnp.int32)
+    mid = starts + kb * sr // 2
+    qp = pmz[mid][:, None] + jnp.linspace(-1.0, 1.0, 16)[None, :]
+    qc = jnp.broadcast_to(charge[mid][:, None], (nqb, 16))
+    interpret = interpret_default()
+
+    def tile_topk(q, qp, qc, s, hvs, pmz, charge):
+        ham = hk.scan_tile_pallas(q, hvs, s // sr, n_blocks=kb,
+                                  interpret=interpret)
+        rp = jax.lax.dynamic_slice(pmz, (s,), (kb * sr,))
+        rc = jax.lax.dynamic_slice(charge, (s,), (kb * sr,))
+        return _find_topk_dual(dim - ham, jnp.abs(qp[:, None] - rp[None, :]),
+                               qp, qc, rc, rp, p)
+
+    def step(q, qp, qc, s, hvs, pmz, charge):
+        return hk.scan_best_pallas(
+            q, qp, qc, qp * (p.ppm_tol * 1e-6), hvs, pmz, charge, s // sr,
+            n_blocks=kb, dim=dim, k=1, open_tol_da=p.open_tol_da,
+            pad_pmz=PAD_PMZ, interpret=interpret)
+
+    outs = {}
+    for name, fn in (("tile + XLA top-k", tile_topk),
+                     ("kernel top-k", step)):
+        run = jax.jit(lambda qs, qp, qc, starts, hvs, pmz, charge, fn=fn:
+                      jax.lax.map(lambda a: fn(*a, hvs, pmz, charge),
+                                  (qs, qp, qc, starts)))
+        args = (qs, qp, qc, starts, hvs, pmz, charge)
+        outs[name] = [np.asarray(o) for o in
+                      jax.block_until_ready(run(*args))]
+        ts = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(*args))
+            ts.append(time.perf_counter() - t0)
+        print(f"{name}: {min(ts) / nqb * 1e3:.4f} ms/qblock", flush=True)
+    a, b = outs.values()
+    print(f"epilogue equal={all((x == y).all() for x, y in zip(a, b))}, "
+          f"queries with a winner: std {int((a[0] >= 0).sum())}, open "
+          f"{int((a[2] >= 0).sum())} of {a[0].size}", flush=True)
 
 
 if __name__ == "__main__":

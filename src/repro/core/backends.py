@@ -6,7 +6,8 @@ be realised:
   * ``matrix`` — computes the full (Qb, Rk) Hamming-distance tile; the
     orchestrator applies the precursor windows and the top-k reduction
     outside the backend. Signature: ``fn(q_hvs, r_hvs, dim) -> (Qb, Rk)
-    int32 hamming``.
+    int32 hamming``. A matrix backend may also carry an in-place scan step
+    (``Backend.scan``) that returns the blocked scan's winners instead.
   * ``fused`` — the paper-faithful path: consumes the PMZ/charge windows and
     returns ranked running winners directly, never materialising the
     (Qb, Rk) similarity matrix. Signature:
@@ -20,8 +21,8 @@ Built-in backends:
   ----------  ------  -----------------------------------------------------
   vpu         matrix  packed XOR + lax.population_count (paper-faithful):
                       on TPU the blocked scan step runs the in-place Pallas
-                      kernel (its ``scan``), XLA on CPU and in the
-                      prefix/rescore tiles
+                      kernel (its ``scan``: tile, windows and top-k in one
+                      pass), XLA on CPU and in the prefix/rescore tiles
   mxu         matrix  ±1 int8 matmul  (D - x·yᵀ)/2  (XLA, MXU formulation)
   kernel_vpu  matrix  Pallas all-pairs Hamming tile kernel
   kernel_mxu  matrix  Pallas MXU Hamming kernel
@@ -69,10 +70,13 @@ class Backend:
     # True where a Pallas kernel computes fn's result.
     pallas: bool = False
     # Optional in-place blocked-scan step of a MATRIX backend: ``scan(q_hvs,
-    # hvs, start_row, rk)`` is fn's tile against rows ``[start_row,
-    # start_row + rk)`` of the whole library ``hvs``, read where they lie
-    # (a Pallas kernel); the scan takes it where ``scan_fits(max_r,
-    # n_words)`` holds for the library's blocking.
+    # q_pmz, q_charge, hvs, pmz, charge, start_row, rk, *, dim, ppm_tol,
+    # open_tol_da, k)`` is the dual-window top-k of fn's tile against rows
+    # ``[start_row, start_row + rk)`` of the whole library, read where they
+    # lie (a Pallas kernel that keeps the winners in VMEM): (std_sim,
+    # std_col, open_sim, open_col), each (Qb, k), col the row's column in
+    # the run or -1. The scan takes it where ``scan_fits(max_r, n_words)``
+    # holds for the library's blocking.
     scan: Callable | None = None
     scan_fits: Callable[[int, int], bool] | None = None
 
@@ -161,9 +165,12 @@ def _fused_xla(q, r, qp, rp, qc, rc, *, dim, ppm_tol, open_tol_da, k):
                              ppm_tol=ppm_tol, open_tol_da=open_tol_da)
 
 
-def _vpu_scan(q, hvs, start_row, rk):
+def _vpu_scan(q, qp, qc, hvs, pmz, charge, start_row, rk, *, dim, ppm_tol,
+              open_tol_da, k):
     from repro.kernels.hamming import ops as hops
-    return hops.scan_tile(q, hvs, start_row, rk=rk)
+    return hops.scan_best(q, qp, qc, hvs, pmz, charge, start_row, rk=rk,
+                          dim=dim, ppm_tol=ppm_tol, open_tol_da=open_tol_da,
+                          k=k)
 
 
 def _vpu_scan_fits(max_r: int, n_words: int) -> bool:

@@ -12,11 +12,14 @@ Semantics (paper-faithful):
     sets the paper's kernel emits, generalised to top-k.
 
 Backends: dispatch goes through the registry in :mod:`repro.core.backends`.
-``matrix`` backends (vpu / mxu / kernel_vpu / kernel_mxu) return the (Qb, Rk)
-Hamming tile and the orchestrator reduces it here; ``fused`` backends (the
-Pallas §II-C kernel and its XLA fallback) consume the PMZ/charge windows and
-return ranked running winners directly, never materialising the (Qb, Rk)
-similarity matrix — the paper's single-pass streaming kernel.
+``fused`` backends (the Pallas §II-C kernel and its XLA fallback) consume
+the PMZ/charge windows and return ranked running winners directly, never
+materialising the (Qb, Rk) similarity matrix — the paper's single-pass
+streaming kernel. So does a ``matrix`` backend's in-place scan step (the
+``vpu`` scan kernel on TPU), which reads the rows and their windows where
+they lie and keeps the winners in VMEM. Elsewhere a ``matrix`` backend
+(vpu / mxu / kernel_vpu / kernel_mxu) returns the (Qb, Rk) Hamming tile and
+the orchestrator reduces it here (``_find_topk_dual``).
 
 Top-k: ``SearchParams.top_k`` (static, default 1) selects how many winners
 per query and window are kept. All :class:`SearchResult` arrays are
@@ -55,8 +58,10 @@ from repro.kernels.topk import select_topk as _select_topk
 from repro.obs.metrics import Metrics
 from repro.obs.trace import span
 
-# Program counters of the resident search: ``lowering_pallas`` /
-# ``lowering_xla`` count searches by how their Hamming tiles were computed.
+# Program counters of the resident search and the slab steps:
+# ``lowering_pallas`` / ``lowering_xla`` count them by how their Hamming
+# tiles were computed, ``epilogue_kernel`` / ``epilogue_xla`` by where their
+# dual-window winners were chosen (``scan_path``).
 METRICS = Metrics()
 
 # Charge multiplier for building monotonic (charge, pmz) sort keys. PMZ values
@@ -125,16 +130,24 @@ def _find_topk_dual(sims, dpmz, q_pmz, q_charge, r_charge, r_pmz,
 # ---------------------------------------------------------------------------
 
 
-def scan_lowering(db: ReferenceDB, p: SearchParams) -> str:
-    """``"pallas"`` where a Pallas kernel computes a search's Hamming tiles,
-    else ``"xla"``: what the ``search.kernel`` span and the ``METRICS``
-    counters record for each search."""
+def scan_path(db: ReferenceDB, p: SearchParams) -> dict[str, str]:
+    """How a search's scan runs, as span attributes, and counted in
+    ``METRICS`` (``<attribute>_<value>``) once per call: ``lowering`` is
+    ``"pallas"`` where a Pallas kernel computes the Hamming tiles, else
+    ``"xla"``; ``epilogue`` is ``"kernel"`` where a kernel chooses the
+    dual-window winners, else ``"xla"`` (``_find_topk_dual`` on the tile)."""
     if p.prefix_words:
-        pallas = backends_mod.tile_backend(p.backend).pallas
+        pallas, in_kernel = backends_mod.tile_backend(p.backend).pallas, False
     else:
         be = backends_mod.get(p.backend)
-        pallas = be.pallas or be.scans_in_place(db.max_r, db.n_words)
-    return "pallas" if pallas else "xla"
+        in_place = be.scans_in_place(db.max_r, db.n_words)
+        pallas = be.pallas or in_place
+        in_kernel = in_place or (be.kind == backends_mod.FUSED and be.pallas)
+    path = {"lowering": "pallas" if pallas else "xla",
+            "epilogue": "kernel" if in_kernel else "xla"}
+    for name, value in path.items():
+        METRICS.counter(f"{name}_{value}").inc()
+    return path
 
 
 def _rows(db: ReferenceDB, start_row, rk: int):
@@ -145,25 +158,28 @@ def _block_body(db: ReferenceDB, dim: int, p: SearchParams,
                 q_hvs, q_pmz, q_charge, start_row):
     """Scan k_blocks*max_r contiguous reference rows for one query block."""
     rk = (p.k_blocks if not p.exhaustive else db.n_blocks) * db.max_r
-    r_pmz = jax.lax.dynamic_slice(db.pmz, (start_row,), (rk,))
-    r_charge = jax.lax.dynamic_slice(db.charge, (start_row,), (rk,))
+    windows = dict(dim=dim, ppm_tol=p.ppm_tol, open_tol_da=p.open_tol_da,
+                   k=p.top_k)
 
     be = backends_mod.get(p.backend)
-    if be.kind == backends_mod.FUSED:
-        std_b, std_a, open_b, open_a = be.fn(
-            q_hvs, _rows(db, start_row, rk), q_pmz, r_pmz, q_charge,
-            r_charge, dim=dim, ppm_tol=p.ppm_tol, open_tol_da=p.open_tol_da,
-            k=p.top_k)
+    if be.scans_in_place(db.max_r, db.n_words):
+        # The kernel reads the rows and their windows where they lie, and
+        # returns the winners: no (rk, W) slice, no (Qb, rk) tile.
+        std_b, std_a, open_b, open_a = be.scan(
+            q_hvs, q_pmz, q_charge, db.hvs, db.pmz, db.charge, start_row,
+            rk, **windows)
     else:
-        if be.scans_in_place(db.max_r, db.n_words):
-            # The kernel reads the rows where they lie: no (rk, W) slice.
-            ham = be.scan(q_hvs, db.hvs, start_row, rk)
+        r_pmz = jax.lax.dynamic_slice(db.pmz, (start_row,), (rk,))
+        r_charge = jax.lax.dynamic_slice(db.charge, (start_row,), (rk,))
+        if be.kind == backends_mod.FUSED:
+            std_b, std_a, open_b, open_a = be.fn(
+                q_hvs, _rows(db, start_row, rk), q_pmz, r_pmz, q_charge,
+                r_charge, **windows)
         else:
-            ham = be.fn(q_hvs, _rows(db, start_row, rk), dim)
-        sims = dim - ham
-        dpmz = jnp.abs(q_pmz[:, None] - r_pmz[None, :])
-        std_b, std_a, open_b, open_a = _find_topk_dual(
-            sims, dpmz, q_pmz, q_charge, r_charge, r_pmz, p)
+            sims = dim - be.fn(q_hvs, _rows(db, start_row, rk), dim)
+            dpmz = jnp.abs(q_pmz[:, None] - r_pmz[None, :])
+            std_b, std_a, open_b, open_a = _find_topk_dual(
+                sims, dpmz, q_pmz, q_charge, r_charge, r_pmz, p)
 
     std_row = jnp.where(std_b >= 0, start_row + std_a, -1)
     open_row = jnp.where(open_b >= 0, start_row + open_a, -1)
@@ -599,9 +615,7 @@ def oms_search(db: ReferenceDB, q_hvs: jax.Array, q_pmz: jax.Array,
     # Padding queries keep their charge (so the block is charge-pure) but are
     # discarded on output.
 
-    lowering = scan_lowering(db, params)
-    METRICS.counter(f"lowering_{lowering}").inc()
-    with span("search.kernel", lowering=lowering):
+    with span("search.kernel", **scan_path(db, params)):
         if params.prefix_words:
             if row_pmz_np is None:
                 row_pmz_np = np.asarray(db.pmz)

@@ -11,11 +11,13 @@ This is the TPU-native port of RapidOMS's FPGA search kernel:
   parallel find_max_score (std + open)  fused dual-window running argmax
                                         accumulated across the ref-block grid
 
-Three kernels:
+Four kernels:
   * ``hamming_matrix_kernel`` — all-pairs Hamming tile (building block,
     validated against the oracle over shape/dtype sweeps);
-  * ``scan_tile_kernel`` — the ``vpu`` backend's blocked-scan tile on TPU:
-    one query block against library rows read in place, rows on lanes;
+  * ``scan_best_kernel`` — the ``vpu`` backend's blocked-scan step on TPU:
+    one query block against library rows read in place, rows on lanes,
+    with the dual-window top-k kept in VMEM (``scan_tile_kernel`` is the
+    same scan returning the whole distance tile);
   * ``fused_search_kernel`` — the full paper kernel: Hamming + PMZ windows +
     dual running *top-k* winners (k static, default 1), one pass over the
     reference stream, no (Q, R) score matrix ever materialised in HBM. The
@@ -100,7 +102,7 @@ def hamming_matrix_pallas(q: jax.Array, r: jax.Array, *, q_tile: int = 16,
 
 
 # ---------------------------------------------------------------------------
-# In-place scan step kernel (the vpu backend's blocked-scan tile on TPU)
+# In-place scan step kernels (the vpu backend's blocked scan on TPU)
 # ---------------------------------------------------------------------------
 #
 # One query block against a contiguous run of rows of the resident library,
@@ -115,6 +117,12 @@ def hamming_matrix_pallas(q: jax.Array, r: jax.Array, *, q_tile: int = 16,
 # crosses lanes. The loops are register-blocked: SCAN_Q_GROUP queries x
 # SCAN_CHUNK_GROUP 128-row chunks keep their accumulators in vregs, so each
 # loaded vreg serves several.
+#
+# Two kernels share that body. ``scan_best_kernel`` is the vpu backend's
+# scan step: it masks each row block's distances by the precursor windows
+# in VMEM and keeps per-lane running winners, so only the (QT, k) winners
+# of each window leave the kernel. ``scan_tile_kernel`` writes the whole
+# (QT, rk) distance tile instead (``scripts/scan_sweep.py`` times it).
 
 SCAN_ROWS = 1024        # rows per row block; start row and length are multiples
 SCAN_BLOCKS_PER_STEP = 4
@@ -122,19 +130,19 @@ SCAN_Q_GROUP = 2
 SCAN_CHUNK_GROUP = 8
 
 
-def scan_tile_kernel(start_ref, q_ref, r_ref, out_ref, qb_ref, rt_ref,
-                     row_ref, *, n_blocks: int, n_sub: int, lib_blocks: int,
+def _scan_row_blocks(start_ref, q_ref, r_ref, qb_ref, rt_ref, row_ref, emit,
+                     *, n_blocks: int, n_sub: int, lib_blocks: int,
                      q_group: int):
     """One grid step: ``n_sub`` row blocks of the run against the query
     block. ``q_ref`` is the (QT, W) int32 query block; ``r_ref`` holds the
     step's (n_sub * SCAN_ROWS, W) rows, read from ``step_first_block`` (see
-    ``scan_tile_pallas``); ``out_ref`` is the step's (QT, n_sub *
-    SCAN_ROWS) int32 output tile. VMEM scratch: ``qb_ref`` (QT, W, 128)
-    holds each query word broadcast along the lanes, filled at the first
-    step; ``rt_ref`` (W, SCAN_ROWS) holds a row block word-major;
-    ``row_ref`` (QT, 1, SCAN_ROWS) its distances, one query per leading
-    index (a store at a traced query row of ``out_ref`` itself would be
-    unaligned)."""
+    ``scan_tile_pallas``). VMEM scratch: ``qb_ref`` (QT, W, 128) holds each
+    query word broadcast along the lanes, filled at the first step;
+    ``rt_ref`` (W, SCAN_ROWS) holds a row block word-major; ``row_ref``
+    (QT, 1, SCAN_ROWS) its distances, one query per leading index (a store
+    at a traced query row of a (QT, SCAN_ROWS) array would be unaligned).
+    For each block ``i`` of the step inside the run, ``emit(i, b)`` takes
+    ``row_ref``; ``b`` is the block's index in ``r_ref``."""
     nq, w = q_ref.shape
     first = pl.program_id(0) * n_sub
 
@@ -181,12 +189,119 @@ def scan_tile_kernel(start_ref, q_ref, r_ref, out_ref, qb_ref, rt_ref,
                 return carry
 
             lax.fori_loop(0, nq // q_group * n_cg, chunks, 0)
-            block = pl.ds(pl.multiple_of(i * SCAN_ROWS, SCAN_ROWS), SCAN_ROWS)
-            for q in range(nq):
-                out_ref[q:q + 1, block] = row_ref[q]
+            emit(i, shift + i)
         return carry
 
     lax.fori_loop(0, n_sub, row_block, 0)
+
+
+def scan_tile_kernel(start_ref, q_ref, r_ref, out_ref, qb_ref, rt_ref,
+                     row_ref, **kw):
+    """``out_ref`` is the step's (QT, n_sub * SCAN_ROWS) int32 output tile
+    of Hamming distances (the rest: ``_scan_row_blocks``)."""
+    def emit(i, _):
+        block = pl.ds(pl.multiple_of(i * SCAN_ROWS, SCAN_ROWS), SCAN_ROWS)
+        for q in range(q_ref.shape[0]):
+            out_ref[q:q + 1, block] = row_ref[q]
+
+    _scan_row_blocks(start_ref, q_ref, r_ref, qb_ref, rt_ref, row_ref, emit,
+                     **kw)
+
+
+def _insert_ranked(ranked, s, a):
+    """Insert candidates (sim ``s``, column ``a``) into per-lane ranked
+    lists ``[(sim, col)] * k``, best first. Only a strictly greater sim
+    goes ahead of an entry: a lane's candidates arrive in ascending column
+    order, so on ties the lowest column stays first."""
+    out, moved = [], None
+    for ls, la in ranked:
+        take = s > ls if moved is None else moved | (s > ls)
+        out.append((lax.select(take, s, ls), lax.select(take, a, la)))
+        s, a, moved = lax.select(take, ls, s), lax.select(take, la, a), take
+    return out
+
+
+def _select_ranked(sims, cols, k: int):
+    """The k best of per-lane ranked lists (each (QT, 128)), ranked by
+    (sim desc, column asc) across the lanes: ((QT, 1) sims, (QT, 1)
+    columns) per rank, -1 and -1 where the rank is empty."""
+    big = jnp.int32(jnp.iinfo(jnp.int32).max)
+    out = []
+    for _ in range(k):
+        best = functools.reduce(lax.max, [
+            jnp.max(s, axis=1, keepdims=True) for s in sims])
+        pick = functools.reduce(lax.min, [
+            jnp.min(jnp.where(s == best, c, big), axis=1, keepdims=True)
+            for s, c in zip(sims, cols)])
+        out.append((jnp.maximum(best, -1), jnp.where(best >= 0, pick, -1)))
+        sims = [jnp.where((s == best) & (c == pick), -2, s)
+                for s, c in zip(sims, cols)]
+    return out
+
+
+def scan_best_kernel(start_ref, q_ref, r_ref, qp_ref, qc_ref, qt_ref, rp_ref,
+                     rc_ref, std_sim_ref, std_col_ref, open_sim_ref,
+                     open_col_ref, qb_ref, rt_ref, row_ref, dist_ref, sim_ref,
+                     col_ref, *, dim: int, k: int, open_tol_da: float,
+                     pad_pmz: float, **kw):
+    """The scan step with the dual-window top-k in VMEM. Per query
+    (QT, 1): ``qp_ref`` precursor m/z, ``qc_ref`` charge, ``qt_ref`` the
+    standard window's half-width in m/z. ``rp_ref`` / ``rc_ref`` hold the
+    step's rows' precursor m/z and charge as (n_sub * 8, 128): row block
+    ``b``'s 128-row chunk ``c`` is row ``8 * b + c``. Each block's
+    distances are assembled into ``dist_ref`` (QT, SCAN_ROWS) and masked
+    128-row chunk by chunk; ``sim_ref`` / ``col_ref`` (2, k, QT, 128) keep
+    each window's k best (sim, run column) per lane across the grid. The
+    last step ranks them across lanes into the four (QT, k) outputs."""
+    nq = q_ref.shape[0]
+    first = pl.program_id(0) * kw["n_sub"]
+    shape = (nq, 128)
+    neg = jnp.full(shape, -1, jnp.int32)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        sim_ref[...] = jnp.full(sim_ref.shape, -1, jnp.int32)
+        col_ref[...] = jnp.full(col_ref.shape, -1, jnp.int32)
+
+    def lanes(x):
+        return lax.broadcast_in_dim(x, shape, (0, 1))
+
+    def emit(i, b):
+        for q in range(nq):
+            dist_ref[q:q + 1, :] = row_ref[q]
+        qp, qc, qt = lanes(qp_ref[...]), lanes(qc_ref[...]), lanes(qt_ref[...])
+        rows = pl.ds(pl.multiple_of(b * 8, 8), 8)
+        rp8, rc8 = rp_ref[rows, :], rc_ref[rows, :]
+        col0 = (first + i) * SCAN_ROWS
+        lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+        ranked = [[(sim_ref[w, j], col_ref[w, j]) for j in range(k)]
+                  for w in range(2)]
+        for c in range(SCAN_ROWS // 128):
+            rp, rc = lanes(rp8[c:c + 1, :]), lanes(rc8[c:c + 1, :])
+            sim = dim - dist_ref[:, c * 128:(c + 1) * 128]
+            dpmz = lax.abs(qp - rp)
+            valid = (rp < pad_pmz) & (qc == rc)
+            col = lane + (col0 + c * 128)
+            for w, inside in enumerate((dpmz <= qt, dpmz <= open_tol_da)):
+                ranked[w] = _insert_ranked(
+                    ranked[w], lax.select(valid & inside, sim, neg), col)
+        for w in range(2):
+            for j, (s, a) in enumerate(ranked[w]):
+                sim_ref[w, j] = s
+                col_ref[w, j] = a
+
+    _scan_row_blocks(start_ref, q_ref, r_ref, qb_ref, rt_ref, row_ref, emit,
+                     **kw)
+
+    @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
+    def _():
+        for w, (s_out, c_out) in enumerate(((std_sim_ref, std_col_ref),
+                                            (open_sim_ref, open_col_ref))):
+            best = _select_ranked([sim_ref[w, j] for j in range(k)],
+                                  [col_ref[w, j] for j in range(k)], k)
+            for r, (s, c) in enumerate(best):
+                s_out[:, r:r + 1] = s
+                c_out[:, r:r + 1] = c
 
 
 def step_first_block(start_block, first, n_sub: int, lib_blocks: int):
@@ -196,6 +311,29 @@ def step_first_block(start_block, first, n_sub: int, lib_blocks: int):
     return jnp.minimum(start_block + first, lib_blocks - n_sub)
 
 
+def _scan_grid(q, hvs, n_blocks: int):
+    """What both scan kernels' launches share: their static parameters,
+    the grid, the query block's and the rows' BlockSpecs, the scratch, and
+    the map from a grid step to the library block its rows are read from."""
+    nq, w = q.shape
+    lib_blocks = hvs.shape[0] // SCAN_ROWS
+    n_sub = min(SCAN_BLOCKS_PER_STEP, n_blocks, lib_blocks)
+
+    def first_block(j, start):
+        return step_first_block(start[0], j * n_sub, n_sub, lib_blocks)
+
+    kw = dict(n_blocks=n_blocks, n_sub=n_sub, lib_blocks=lib_blocks,
+              q_group=math.gcd(SCAN_Q_GROUP, nq))
+    specs = [pl.BlockSpec((nq, w), lambda j, start: (0, 0)),
+             pl.BlockSpec((pl.Element(n_sub * SCAN_ROWS), pl.Element(w)),
+                          lambda j, start: (first_block(j, start)
+                                            * SCAN_ROWS, 0))]
+    scratch = [pltpu.VMEM((nq, w, 128), jnp.int32),
+               pltpu.VMEM((w, SCAN_ROWS), jnp.int32),
+               pltpu.VMEM((nq, 1, SCAN_ROWS), jnp.int32)]
+    return kw, (-(-n_blocks // n_sub),), specs, scratch, first_block
+
+
 def scan_tile_pallas(q: jax.Array, hvs: jax.Array, start_block: jax.Array, *,
                      n_blocks: int, interpret: bool = True) -> jax.Array:
     """q (QT, W) uint32 against rows ``[start_block, start_block + n_blocks)
@@ -203,30 +341,17 @@ def scan_tile_pallas(q: jax.Array, hvs: jax.Array, start_block: jax.Array, *,
     int32 Hamming distances. ``start_block`` is a traced int32 scalar; the
     caller guarantees the rows lie inside ``hvs``, R % SCAN_ROWS == 0 and
     W % 8 == 0."""
-    nq, w = q.shape
-    lib_blocks = hvs.shape[0] // SCAN_ROWS
-    n_sub = min(SCAN_BLOCKS_PER_STEP, n_blocks, lib_blocks)
-    steps = -(-n_blocks // n_sub)
-    q_group = math.gcd(SCAN_Q_GROUP, nq)
-
-    def rows_map(j, start):
-        return (step_first_block(start[0], j * n_sub, n_sub, lib_blocks)
-                * SCAN_ROWS, 0)
-
+    nq = q.shape[0]
+    kw, grid, specs, scratch, _ = _scan_grid(q, hvs, n_blocks)
+    n_sub = kw["n_sub"]
     out = pl.pallas_call(
-        functools.partial(scan_tile_kernel, n_blocks=n_blocks, n_sub=n_sub,
-                          lib_blocks=lib_blocks, q_group=q_group),
+        functools.partial(scan_tile_kernel, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(steps,),
-            in_specs=[pl.BlockSpec((nq, w), lambda j, start: (0, 0)),
-                      pl.BlockSpec((pl.Element(n_sub * SCAN_ROWS),
-                                    pl.Element(w)), rows_map)],
+            num_scalar_prefetch=1, grid=grid, in_specs=specs,
             out_specs=pl.BlockSpec((nq, n_sub * SCAN_ROWS),
                                    lambda j, start: (0, j)),
-            scratch_shapes=[pltpu.VMEM((nq, w, 128), jnp.int32),
-                            pltpu.VMEM((w, SCAN_ROWS), jnp.int32),
-                            pltpu.VMEM((nq, 1, SCAN_ROWS), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((nq, steps * n_sub * SCAN_ROWS),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((nq, grid[0] * n_sub * SCAN_ROWS),
                                        jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -234,6 +359,52 @@ def scan_tile_pallas(q: jax.Array, hvs: jax.Array, start_block: jax.Array, *,
     )(start_block.astype(jnp.int32).reshape(1),
       lax.bitcast_convert_type(q, jnp.int32), hvs)
     return out[:, :n_blocks * SCAN_ROWS]
+
+
+def scan_best_pallas(q, q_pmz, q_charge, q_std_tol, hvs, pmz, charge,
+                     start_block, *, n_blocks: int, dim: int, k: int,
+                     open_tol_da: float, pad_pmz: float,
+                     interpret: bool = True):
+    """The dual-window top-k of q (QT, W) uint32 over rows ``[start_block,
+    start_block + n_blocks) * SCAN_ROWS`` of ``hvs``, read in place with
+    their precursor m/z ``pmz`` (R,) f32 and ``charge`` (R,) int32. Per
+    query: ``q_pmz``, ``q_charge`` and ``q_std_tol``, the standard window's
+    half-width in m/z. A row is in a query's window where its pmz is below
+    ``pad_pmz``, its charge equals the query's and ``|q_pmz - pmz|`` is at
+    most ``q_std_tol`` (standard) or ``open_tol_da`` (open).
+
+    Returns (std_sim, std_col, open_sim, open_col), each (QT, k) int32,
+    ranked by (sim desc, col asc): sim = dim - hamming, col the row's
+    column in the run; -1 and -1 past the window's candidates. Caller
+    guarantees as for ``scan_tile_pallas``."""
+    nq = q.shape[0]
+    kw, grid, specs, scratch, first_block = _scan_grid(q, hvs, n_blocks)
+    sub = SCAN_ROWS // 128
+    per_query = pl.BlockSpec((nq, 1), lambda j, start: (0, 0))
+    per_row = pl.BlockSpec(
+        (pl.Element(kw["n_sub"] * sub), pl.Element(128)),
+        lambda j, start: (first_block(j, start) * sub, 0))
+    winners = pl.BlockSpec((nq, k), lambda j, start: (0, 0))
+    return pl.pallas_call(
+        functools.partial(scan_best_kernel, dim=dim, k=k,
+                          open_tol_da=open_tol_da, pad_pmz=pad_pmz, **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=specs + [per_query] * 3 + [per_row] * 2,
+            out_specs=[winners] * 4,
+            scratch_shapes=scratch + [
+                pltpu.VMEM((nq, SCAN_ROWS), jnp.int32),
+                pltpu.VMEM((2, k, nq, 128), jnp.int32),
+                pltpu.VMEM((2, k, nq, 128), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((nq, k), jnp.int32)] * 4,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(start_block.astype(jnp.int32).reshape(1),
+      lax.bitcast_convert_type(q, jnp.int32), hvs,
+      q_pmz.reshape(nq, 1), q_charge.reshape(nq, 1),
+      q_std_tol.reshape(nq, 1), pmz.reshape(-1, 128),
+      charge.reshape(-1, 128))
 
 
 # ---------------------------------------------------------------------------

@@ -86,13 +86,21 @@ def scan_tile_fits(max_r: int, n_words: int) -> bool:
     return max_r % _k.SCAN_ROWS == 0 and n_words % 128 == 0
 
 
-@partial(jax.jit, static_argnames=("rk", "interpret"))
-def scan_tile(q, hvs, start_row, *, rk: int, interpret: bool | None = None):
-    """(QT, W) queries against rows ``[start_row, start_row + rk)`` of the
-    library ``hvs``, read in place -> (QT, rk) int32 Hamming distances.
-    ``start_row`` (traced) and ``rk`` are multiples of ``SCAN_ROWS``."""
+@partial(jax.jit, static_argnames=("rk", "dim", "ppm_tol", "open_tol_da",
+                                   "k", "interpret"))
+def scan_best(q, q_pmz, q_charge, hvs, pmz, charge, start_row, *, rk: int,
+              dim: int, ppm_tol: float, open_tol_da: float, k: int,
+              interpret: bool | None = None):
+    """Dual-window top-k of the (QT, W) queries over rows ``[start_row,
+    start_row + rk)`` of the library ``hvs`` / ``pmz`` / ``charge``, read in
+    place -> (std_sim, std_col, open_sim, open_col), each (QT, k) int32
+    with col the row's column in the run: what ``core.search.
+    _find_topk_dual`` returns for those rows' distance tile, computed
+    without one. ``start_row`` (traced) and ``rk`` are multiples of
+    ``SCAN_ROWS``."""
     if interpret is None:
         interpret = interpret_default()
-    return _k.scan_tile_pallas(q, hvs, start_row // _k.SCAN_ROWS,
-                               n_blocks=rk // _k.SCAN_ROWS,
-                               interpret=interpret)
+    return _k.scan_best_pallas(
+        q, q_pmz, q_charge, q_pmz * (ppm_tol * 1e-6), hvs, pmz, charge,
+        start_row // _k.SCAN_ROWS, n_blocks=rk // _k.SCAN_ROWS, dim=dim,
+        k=k, open_tol_da=open_tol_da, pad_pmz=PAD_PMZ, interpret=interpret)

@@ -59,11 +59,11 @@ import numpy as np
 
 from repro.analysis.registry import contract, declare
 from repro.obs.trace import span
-from repro.core.search import (METRICS, SearchParams, SearchResult,
+from repro.core.search import (SearchParams, SearchResult,
                                _NEG_THRESHOLD, _prefix_flags,
                                _rescore_rows_padded, _search_sorted_padded,
                                kth_thresholds, pad_candidate_rows,
-                               plan_seed_rows, row_bucket, scan_lowering,
+                               plan_seed_rows, row_bucket, scan_path,
                                sort_pad_plan, validate_prefix_words,
                                validate_search_params)
 from repro.kernels.topk import merge_topk, select_topk
@@ -463,11 +463,9 @@ class StreamingEngine:
                 rows_read += n_real
                 q0, n_qb = _qblock_range(first, stop, s, nqb)
                 pairs += n_qb * QB * rows_per_qblock
-                lowering = scan_lowering(db_np, local)
-                METRICS.counter(f"lowering_{lowering}").inc()
                 with span("serve.slab.search", slab=s, rows=n_real,
                           bytes=n_real * W * 4, qblocks=n_qb,
-                          lowering=lowering):
+                          **scan_path(db_np, local)):
                     dev = self._device_for(j)
                     prev = runs.get(dev)
                     if prev is None:

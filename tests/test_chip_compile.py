@@ -17,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import repro.kernels
+from repro.analysis import contracts, registry
 from repro.core.blocking import ReferenceDB
 from repro.core.search import SearchParams, _search_sorted_padded
 from repro.kernels.hamming import ops as hops
@@ -143,19 +144,42 @@ def _queries(s, n):
     return s((n, W), jnp.uint32), s((n,), jnp.float32), s((n,), jnp.int32)
 
 
+def _keeps_contracts(fn, args, targets, ctx):
+    """The traced program keeps every declared contract of ``targets``
+    (``peak_intermediate`` among them) and holds no (q_block, rk) distance
+    tile outside a kernel: the scan step returns winners, not a tile."""
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    results = [contracts.evaluate(d, jaxpr, ctx) for t in targets
+               for d in registry.declarations(t)
+               if d.contract != "recompile_guard"]
+    results.append(contracts.check_no_materialize(
+        jaxpr, q_block=Q, r_rows=ctx["rk"], target="scan step"))
+    for r in results:
+        assert r.passed, r.as_dict()
+
+
+def _search_ctx(k_blocks, **extra):
+    return {"dim": DIM, "n_words": W, "q_block": Q, "rk": k_blocks * 1024,
+            "top_k": 1, **extra}
+
+
 @pytest.mark.parametrize("rows,k_blocks", [(IPRG_ROWS, 129), (HEK_ROWS, 323)],
                          ids=["iprg2012", "hek293"])
 def test_vpu_search_runs_the_scan_kernel(one_chip, tpu_platform, rows,
                                          k_blocks):
     """The vpu blocked scan at the benchmark's row shapes (``max_r`` 1024)
-    holds the in-place scan kernel and fits one chip."""
+    holds the in-place scan kernel, with the dual-window top-k inside it,
+    keeps the ``search:vpu`` contracts and fits one chip."""
     s = functools.partial(_spec, one_chip)
-    compiled = _search_sorted_padded.lower(
-        _library(s, rows), *_queries(s, 2048 + 2 * Q),
-        params=SearchParams(k_blocks=k_blocks, backend="vpu"),
-        dim=DIM).compile()
+    args = (_library(s, rows), *_queries(s, 2048 + 2 * Q))
+    params = SearchParams(k_blocks=k_blocks, backend="vpu")
+    compiled = _search_sorted_padded.lower(*args, params=params,
+                                           dim=DIM).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits_one_chip(compiled)
+    _keeps_contracts(
+        functools.partial(_search_sorted_padded, params=params, dim=DIM),
+        args, ["search:vpu"], _search_ctx(k_blocks))
 
 
 @pytest.mark.parametrize("slab_rows,n_queries,n_qb,k_blocks", [
@@ -174,13 +198,18 @@ def test_vpu_slab_search_runs_the_scan_kernel(one_chip, tpu_platform,
 
     s = functools.partial(_spec, one_chip)
     run = tuple(s((n_queries, 1), jnp.int32) for _ in range(4))
+    args = (run, _library(s, slab_rows), *_queries(s, n_queries),
+            s((), jnp.int32), s((), jnp.int32))
+    params = SearchParams(k_blocks=k_blocks, backend="vpu")
     compiled = _search_sorted_padded_slab.lower(
-        run, _library(s, slab_rows), *_queries(s, n_queries),
-        s((), jnp.int32), s((), jnp.int32),
-        params=SearchParams(k_blocks=k_blocks, backend="vpu"),
-        dim=DIM, n_qb=n_qb).compile()
+        *args, params=params, dim=DIM, n_qb=n_qb).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits_one_chip(compiled)
+    _keeps_contracts(
+        functools.partial(_search_sorted_padded_slab, params=params,
+                          dim=DIM, n_qb=n_qb),
+        args, ["search:vpu", "serve:slab_step"],
+        _search_ctx(k_blocks, slab_rows=slab_rows))
 
 
 def test_sharded_vpu_search_runs_the_scan_kernel(v5e, tpu_platform):
@@ -207,8 +236,10 @@ def test_sharded_vpu_search_runs_the_scan_kernel(v5e, tpu_platform):
     def search(db, qh, qp, qc):
         return sharded_search(db, qh, qp, qc, params, dim=DIM, mesh=mesh)[0]
 
-    compiled = jax.jit(search).lower(
-        _library(by_row, IPRG_ROWS), *_queries(replicated, 2048 + 2 * Q)
-    ).compile()
+    args = (_library(by_row, IPRG_ROWS), *_queries(replicated, 2048 + 2 * Q))
+    compiled = jax.jit(search).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits_one_chip(compiled)
+    jaxpr = jax.make_jaxpr(search)(*args)
+    assert contracts.check_no_materialize(
+        jaxpr, q_block=Q, r_rows=129 * 1024).passed

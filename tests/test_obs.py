@@ -472,3 +472,51 @@ def test_streamed_slab_gather_runs_on_the_prefetch_thread_under_scan():
                    for e in evs_n), name
     assert {e.attrs["lowering"] for e in evs
             if e.name == "serve.slab.search"} == {"xla"}
+
+
+@pytest.mark.parametrize("forced", [False, True], ids=["cpu", "forced"])
+def test_scan_epilogue_is_named_on_every_search_and_slab_step(request,
+                                                              forced):
+    """Each resident search (``search.kernel``) and each slab step of a
+    streamed one (``serve.slab.search``) records where its dual-window
+    winners were chosen: ``kernel`` where the vpu scan step keeps them in
+    VMEM (forced here through the test's hook), ``xla`` on the CPU, where
+    ``_find_topk_dual`` reduces the tile; ``epilogue_kernel`` /
+    ``epilogue_xla`` count one per search and per slab step. The answers
+    do not change."""
+    import tempfile
+
+    from repro.core import OMSConfig, OMSPipeline, search
+    from repro.data.spectra import LibraryConfig, make_dataset
+
+    cfg = OMSConfig(dim=256, n_levels=8, max_r=1024, q_block=16)
+    ds = make_dataset(LibraryConfig(n_refs=300, n_queries=16, seed=7))
+    with tempfile.TemporaryDirectory() as d:
+        OMSPipeline.ingest(cfg, ds.refs, d + "/store")
+        pipes = (OMSPipeline.from_store(d + "/store", cfg),
+                 OMSPipeline.from_store(d + "/store", cfg, resident=False,
+                                        slab_rows=1024))
+        hvs, qp, qc = pipes[0].encode_queries(ds.queries)
+        plain = [p.search_encoded(hvs, qp, qc).result for p in pipes]
+        if forced:
+            request.getfixturevalue("force_scan_kernel")
+        want = "kernel" if forced else "xla"
+        for pipe, base, name in zip(pipes, plain, ("search.kernel",
+                                                   "serve.slab.search")):
+            before = search.METRICS.snapshot()
+            t = install(Tracer())
+            try:
+                got = pipe.search_encoded(hvs, qp, qc).result
+            finally:
+                uninstall()
+            after = search.METRICS.snapshot()
+            evs = [e for e in t.events() if e.name == name]
+            assert evs and {e.attrs["epilogue"] for e in evs} == {want}
+            if name == "serve.slab.search":
+                assert len(evs) == pipe.engine.last_stats.n_scanned > 1
+            for c in ("epilogue_kernel", "epilogue_xla"):
+                grew = after.get(c, 0) - before.get(c, 0)
+                assert grew == (len(evs) if c == f"epilogue_{want}" else 0), c
+            for f in base._fields:
+                assert (np.asarray(getattr(base, f))
+                        == np.asarray(getattr(got, f))).all(), (name, f)
