@@ -1,8 +1,6 @@
-"""Roofline reporter: reads results/dryrun/*.json into the §Roofline table.
-
-Also derives the OMS-engine roofline (the paper's workload) analytically from
-the same v5e constants, for the §Perf comparison of the paper-faithful VPU
-path vs the beyond-paper MXU path.
+"""Roofline reporter: the OMS-engine roofline (the paper's workload),
+derived analytically from the v5e constants, for the §Perf comparison of the
+paper-faithful VPU path vs the beyond-paper MXU path.
 
 ``tune_sweeps`` runs the tile-sweep harness (``repro.tune.sweep``) over the
 tunable backends and reports measured-vs-modeled: per backend one row whose
@@ -16,36 +14,12 @@ non-structural derived tokens. Env knobs: ``BENCH_TUNE_DIM`` / ``_K`` /
 """
 from __future__ import annotations
 
-import glob
-import json
 import os
 
 from benchmarks.common import emit
 from repro.utils.roofline import PEAKS
 
 V5E = PEAKS["TPU v5 lite"]   # the analytic OMS model below is for this chip
-
-
-def lm_table(out_dir="results/dryrun"):
-    rows = []
-    for f in sorted(glob.glob(os.path.join(out_dir, "*.json"))):
-        r = json.load(open(f))
-        if r.get("multi_pod"):
-            continue
-        if r["status"] != "ok":
-            emit(f"roofline/{r['arch']}_x_{r['shape']}", 0.0,
-                 f"SKIPPED: {r.get('reason', r.get('error', ''))[:80]}")
-            continue
-        roof = r["roofline"]
-        t_bound = max(roof["t_compute_s"], roof["t_memory_s"],
-                      roof["t_collective_s"])
-        emit(f"roofline/{r['arch']}_x_{r['shape']}", t_bound * 1e6,
-             f"tC={roof['t_compute_s']:.2e}s tM={roof['t_memory_s']:.2e}s "
-             f"tX={roof['t_collective_s']:.2e}s bneck={roof['bottleneck']} "
-             f"useful={roof['useful_flops_frac']*100:.1f}% "
-             f"roofline_frac={roof['roofline_fraction']*100:.2f}%")
-        rows.append(r)
-    return rows
 
 
 def oms_roofline(n_refs=1_160_000, n_queries=2048, dhv=4096, q_block=64,
@@ -106,8 +80,6 @@ def tune_sweeps(dim=512, k=2, q_rows=16, r_rows=1024, grid="tiny", iters=3):
 
 
 def main():
-    if glob.glob("results/dryrun/*.json"):
-        lm_table()
     oms_roofline()
     tune_sweeps(dim=int(os.environ.get("BENCH_TUNE_DIM", 512)),
                 k=int(os.environ.get("BENCH_TUNE_K", 2)),

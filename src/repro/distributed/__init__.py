@@ -1,6 +1,2 @@
-from repro.distributed.sharding import (
-    batch_pspecs,
-    cache_pspecs,
-    opt_state_pspecs,
-    param_pspecs,
-)
+"""OMS search over a device mesh: the library sharded across the ``model``
+axis, per-shard winners merged by all-gather (``collectives``)."""

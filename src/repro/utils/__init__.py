@@ -1,5 +1,2 @@
-from repro.utils.treeutil import (
-    tree_bytes,
-    tree_count,
-    tree_map_with_path_str,
-)
+"""Compiled-program cost model (``hlo_cost``) and the device peaks it is
+read against (``roofline``), both used by the tile sweep in ``repro.tune``."""

@@ -1,6 +1,6 @@
 # NOTE: deliberately NO XLA_FLAGS here — smoke tests and benches must see the
-# real single-device CPU; only launch/dryrun.py forces 512 host devices, and
-# multi-device tests spawn subprocesses (tests/test_distributed.py).
+# real single-device CPU; multi-device tests spawn subprocesses with forced
+# host devices (tests/test_distributed.py).
 import os
 import sys
 

@@ -10,6 +10,9 @@ The import-graph half pins the layering: the repo graph stays cycle-free,
 the two declared leaf modules import nothing from ``repro``, and the cycle
 detector itself is exercised on a synthetic cyclic package.
 """
+import ast
+import fnmatch
+import glob
 import os
 import textwrap
 
@@ -27,6 +30,17 @@ from repro.analysis.jaxpr_walk import (find_shape_carriers, format_eqn,
                                        peak_intermediate)
 
 SRC_ROOT = os.path.join(os.path.dirname(__file__), "..", "src")
+BENCH_ROOT = os.path.join(os.path.dirname(__file__), "..", "bench")
+
+# Modules that neither entry point (``repro.launch.oms``, the ``bench/``
+# modules) reaches, each on purpose: the tests' plain oracles, and the
+# sharded search that waits for its four-chip cell.
+UNREACHED_ON_PURPOSE = (
+    "repro.core.baselines",          # exhaustive search the tests compare to
+    "repro.kernels.*.ref",           # jnp references of the Pallas kernels
+    "repro.distributed",             # sharded search over a mesh, reached
+    "repro.distributed.collectives",  # only by tests until it gets a cell
+)
 
 # Distinct sizes so shape-membership tests cannot collide.
 QB, RK, W = 8, 96, 16
@@ -318,6 +332,56 @@ class TestImportGraph:
 
     def test_self_loop_is_a_cycle(self):
         assert find_cycles({"m": ["m"]}) == [["m"]]
+
+    def test_every_module_is_reachable_from_an_entry_point(self):
+        # Reachable = imported, at any depth and from function bodies too,
+        # by the launcher or by a benchmark module; importing a module runs
+        # its parent packages' __init__ as well. The module set is the
+        # import graph's; the edges are every absolute import in a file.
+        modules = set(build_import_graph(SRC_ROOT))
+
+        def imports(path):
+            with open(path) as f:
+                tree = ast.parse(f.read(), filename=path)
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Import):
+                    yield from (a.name for a in node.names)
+                elif isinstance(node, ast.ImportFrom) and not node.level:
+                    yield from (f"{node.module}.{a.name}" for a in node.names)
+
+        def path_of(mod):
+            base = os.path.join(SRC_ROOT, *mod.split("."))
+            return base + ".py" if os.path.isfile(base + ".py") else \
+                os.path.join(base, "__init__.py")
+
+        def resolve(name):
+            parts = name.split(".")
+            for i in range(len(parts), 0, -1):
+                if ".".join(parts[:i]) in modules:
+                    return ".".join(parts[:i])
+            return None
+
+        todo = ["repro.launch.oms"]
+        for path in glob.glob(os.path.join(BENCH_ROOT, "**", "*.py"),
+                              recursive=True):
+            todo += imports(path)
+        reached: set[str] = set()
+        while todo:
+            mod = resolve(todo.pop())
+            if mod is None or mod in reached:
+                continue
+            reached.add(mod)
+            parts = mod.split(".")
+            todo += [".".join(parts[:i]) for i in range(1, len(parts))]
+            todo += imports(path_of(mod))
+
+        unreached = sorted(modules - reached)
+        stray = [m for m in unreached if not any(
+            fnmatch.fnmatch(m, pat) for pat in UNREACHED_ON_PURPOSE)]
+        assert stray == [], stray
+        stale = [pat for pat in UNREACHED_ON_PURPOSE
+                 if not fnmatch.filter(unreached, pat)]
+        assert stale == [], f"reached now, drop from the tuple: {stale}"
 
 
 # ---------------------------------------------------------------------------

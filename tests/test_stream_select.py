@@ -9,11 +9,23 @@ random spectra at slab sizes that split windows and charge boundaries, with
 bucket padding and shifted ranges exercised, on the full-width and the
 prefix path; and the engine's compared-pairs counter equals an independent
 count of the selected (q-block, slab) pairs.
+
+The three-way comparison also runs under the settings of both benchmark
+deployments (bin width, ppm and open windows, intensity levels, m/z range,
+q-block and the library generator's shape from ``bench/configs/{iprg2012,
+hek293}.json``; library, queries, HV width and block size cut to CPU size)
+at charges {2 only, 2 and 3}, and every side is held to a numpy oracle: the
+full Hamming matrix, masked by each window and ranked by (sim desc, row
+asc). The two scan loops (``core/search.py`` resident, ``serve/engine.py``
+streamed) must keep agreeing with it whatever folds them.
 """
+import json
+import os
+
 import numpy as np
 import pytest
 
-from repro.core import OMSConfig, OMSPipeline
+from repro.core import OMSConfig, OMSPipeline, packing
 from repro.core.baselines import exhaustive_params
 from repro.core.search import oms_search, sort_pad_plan
 from repro.data.spectra import LibraryConfig, make_dataset
@@ -42,6 +54,74 @@ def setup(tmp_path_factory):
     return ds, store, resident, encoded
 
 
+CONFIGS = os.path.join(os.path.dirname(__file__), "..", "bench", "configs")
+
+
+def _deployment(name: str, charges: tuple[int, ...]):
+    """(OMSConfig, LibraryConfig) of a benchmark deployment at CPU size:
+    240 targets + 240 decoys in blocks of 32 rows, 40 queries."""
+    with open(os.path.join(CONFIGS, f"{name}.json")) as f:
+        c = json.load(f)
+    enc, srch, lib = c["encoding"], c["search"], c["library"]
+    cfg = OMSConfig(dim=512, max_r=32, n_levels=enc["n_levels"],
+                    bin_size=enc["bin_size"], mz_min=enc["mz_min"],
+                    mz_max=enc["mz_max"], ppm_tol=srch["ppm_tol"],
+                    open_tol_da=srch["open_tol_da"], q_block=srch["q_block"],
+                    backend=srch["backend"], add_decoys=lib["add_decoys"])
+    keep = ("max_peaks", "min_peaks", "mz_min", "mz_max", "pmz_min",
+            "pmz_max", "modified_frac", "dropout", "mz_jitter",
+            "intensity_jitter")
+    data = LibraryConfig(n_refs=240, n_queries=40, charges=charges,
+                         open_tol_da=srch["open_tol_da"], seed=7,
+                         **{k: lib[k] for k in keep})
+    return cfg, data
+
+
+@pytest.fixture(scope="module")
+def searches(setup, tmp_path_factory):
+    """(cfg, store, resident pipeline, encoded queries) per setting, built
+    on first use: ``("unit", (2, 3))`` is ``setup``'s, the others a
+    benchmark deployment's at the given charges."""
+    built = {("unit", (2, 3)): (CFG,) + setup[1:]}
+
+    def get(name, charges):
+        if (name, charges) not in built:
+            cfg, data = _deployment(name, charges)
+            ds = make_dataset(data)
+            path = tmp_path_factory.mktemp(f"{name}_{len(charges)}") / "s"
+            store = OMSPipeline.ingest(cfg, ds.refs, str(path))
+            resident = OMSPipeline.from_store(store, cfg)
+            built[name, charges] = (cfg, store, resident,
+                                    resident.encode_queries(ds.queries))
+        return built[name, charges]
+
+    return get
+
+
+def _oracle_topk(cfg, db, hvs, qp, qc, k):
+    """Top-k of both windows from the full (Q, R) similarity matrix: the
+    in-window, same-charge, non-padding rows ranked by (sim desc, row asc),
+    -1-padded. Fields and order as ``SearchResult``."""
+    sims = np.asarray(cfg.dim - packing.hamming_matrix_packed(hvs, db.hvs))
+    qp, qc = np.asarray(qp)[:, None], np.asarray(qc)[:, None]
+    pmz, orig = np.asarray(db.pmz)[None, :], np.asarray(db.orig_idx)
+    dpmz = np.abs(qp - pmz)
+    valid = (np.asarray(db.charge)[None, :] == qc) & (orig >= 0)[None, :]
+    out = {}
+    for window, mask in (("std", dpmz <= qp * np.float32(cfg.ppm_tol * 1e-6)),
+                         ("open", dpmz <= np.float32(cfg.open_tol_da))):
+        mask &= valid
+        # a stable sort on -sim keeps equal sims in row order
+        rows = np.argsort(np.where(mask, -sims, 1), axis=1,
+                          kind="stable")[:, :k]
+        hit = np.take_along_axis(mask, rows, axis=1)
+        out[f"{window}_idx"] = np.where(hit, orig[rows], -1)
+        out[f"{window}_sim"] = np.where(
+            hit, np.take_along_axis(sims, rows, axis=1), -1)
+        out[f"{window}_row"] = np.where(hit, rows, -1)
+    return out
+
+
 def _slab_calls(monkeypatch):
     """Record (q0, n_qb) of every slab step."""
     calls = []
@@ -55,20 +135,37 @@ def _slab_calls(monkeypatch):
     return calls
 
 
-# 32: one block per slab, every window spans several slabs; 96 and 160
-# split windows and put a charge boundary inside a slab; 1 << 30: one slab.
-@pytest.mark.parametrize("slab_rows", [32, 96, 160, 1 << 30])
-@pytest.mark.parametrize("top_k", [1, 3])
-def test_streamed_equals_resident_and_exhaustive(setup, monkeypatch,
-                                                 slab_rows, top_k):
-    ds, store, resident, (hvs, qp, qc) = setup
+# Unit settings: 32 rows is one block per slab, every window spans several
+# slabs; 96 and 160 split windows and put a charge boundary inside a slab;
+# 1 << 30 is one slab. Deployments: 96 rows (five slabs) or one slab.
+STREAM_CASES = [
+    pytest.param("unit", (2, 3), top_k, slab_rows, id=f"{top_k}-{slab_rows}")
+    for top_k in (1, 3) for slab_rows in (32, 96, 160, 1 << 30)
+] + [
+    pytest.param(name, charges, top_k, slab_rows,
+                 id=f"{name}-z{''.join(map(str, charges))}-{top_k}-"
+                    f"{'one' if slab_rows > 480 else 'several'}")
+    for name in ("iprg2012", "hek293") for charges in ((2,), (2, 3))
+    for top_k in (1, 4) for slab_rows in (96, 1 << 30)
+]
+
+
+@pytest.mark.parametrize("name,charges,top_k,slab_rows", STREAM_CASES)
+def test_streamed_equals_resident_and_exhaustive(searches, monkeypatch, name,
+                                                 charges, top_k, slab_rows):
+    cfg, store, resident, (hvs, qp, qc) = searches(name, charges)
+    assert set(np.unique(np.asarray(qc)).tolist()) == set(charges)
     params = resident.search_params(qp, qc, top_k=top_k)
-    want = oms_search(resident.db, hvs, qp, qc, params, dim=CFG.dim)
+    want = oms_search(resident.db, hvs, qp, qc, params, dim=cfg.dim)
+    oracle = _oracle_topk(cfg, resident.db, hvs, qp, qc, top_k)
+    for f in want._fields:
+        assert (np.asarray(getattr(want, f)) == oracle[f]).all(), \
+            ("resident vs oracle", f)
     plain = oms_search(resident.db, hvs, qp, qc, exhaustive_params(params),
-                       dim=CFG.dim)
+                       dim=cfg.dim)
     _assert_result_equal(want, plain, ctx="resident vs exhaustive")
 
-    stream = OMSPipeline.from_store(store, CFG, resident=False,
+    stream = OMSPipeline.from_store(store, cfg, resident=False,
                                     slab_rows=slab_rows)
     calls = _slab_calls(monkeypatch)
     got = stream.search_encoded(hvs, qp, qc, top_k=top_k)
@@ -77,12 +174,21 @@ def test_streamed_equals_resident_and_exhaustive(setup, monkeypatch,
     for w, g in ((res.open_fdr, got.open_fdr), (res.std_fdr, got.std_fdr)):
         assert (np.asarray(w.accept) == np.asarray(g.accept)).all()
 
-    gather, _ = sort_pad_plan(qp, qc, CFG.q_block)
-    nqb = int(gather.shape[0]) // CFG.q_block
+    gather, _ = sort_pad_plan(qp, qc, cfg.q_block)
+    nqb = int(gather.shape[0]) // cfg.q_block
     assert len(calls) == stream.engine.last_stats.n_scanned
+    assert (stream.engine.plan.n_slabs == 1) == (slab_rows == 1 << 30)
     if stream.engine.plan.n_slabs > 1:
         # selection is on: some slab scans fewer q-blocks than the batch has
         assert min(n for _, n in calls) < nqb
+
+    # The windows did real work: the ppm window matches only some queries,
+    # the open window more, and top_k > 1 fills deeper ranks.
+    std_hits = (np.asarray(want.std_idx)[:, 0] >= 0).sum()
+    open_idx = np.asarray(want.open_idx)
+    assert 0 < std_hits < (open_idx[:, 0] >= 0).sum()
+    if top_k > 1:
+        assert (open_idx[:, top_k - 1] >= 0).any()
 
 
 def test_bucket_padding_and_shifted_ranges_stay_exact(setup, monkeypatch):
